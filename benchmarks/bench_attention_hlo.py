@@ -62,6 +62,7 @@ print(json.dumps(out))
 def run(csv_rows):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    env["JAX_PLATFORMS"] = "cpu"     # virtual devices, never the chip
     env["PYTHONPATH"] = f"{SRC}:{ROOT}"
     r = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                        capture_output=True, text=True, timeout=900)

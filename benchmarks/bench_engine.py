@@ -142,6 +142,7 @@ def run(csv_rows, N: int = 1024):
     for P in [4, 8]:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
+        env["JAX_PLATFORMS"] = "cpu"     # virtual devices, never the chip
         env["PYTHONPATH"] = str(SRC)
         r = subprocess.run([sys.executable, "-c", _CHILD, str(P), str(N),
                             ",".join(modes)],

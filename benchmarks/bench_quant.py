@@ -229,6 +229,7 @@ def run(csv_rows, N: int = 2048, d: int = 128):
         results["bytes_per_device"][str(P)] = _bytes_per_device(N, d, P)
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P}"
+        env["JAX_PLATFORMS"] = "cpu"     # virtual devices, never the chip
         env["PYTHONPATH"] = str(SRC)
         r = subprocess.run([sys.executable, "-c", _CHILD, str(P), str(N),
                             str(d)],

@@ -1,10 +1,1 @@
-"""repro — quorum all-pairs reproduction (see DESIGN.md).
-
-Importing any submodule installs the jax version-compat shims first
-(:mod:`repro._compat`), so the package presents one API surface across the
-jax versions we run on.
-"""
-
-from . import _compat
-
-_compat.install()
+"""repro — quorum all-pairs reproduction (see DESIGN.md)."""

@@ -60,13 +60,13 @@ def forces_fn(mesh, axis_name: str = "q", strategy: str = "quorum",
     if strategy == "quorum":
         sched = build_schedule(P)
         masks = jnp.asarray(pair_mask_table(sched))
+        from ..kernels import ops as kops
         batch_fn = None
         if use_kernel:
             if mode not in ("batched", "auto"):
                 raise ValueError(
                     f"use_kernel needs the batched mode (got mode={mode!r}); "
                     "the fused kernel only replaces the batched inner step")
-            from ..kernels import ops as kops
             batch_fn = functools.partial(kops.pairwise_batch_forces,
                                          softening=SOFTENING)
 
@@ -77,7 +77,8 @@ def forces_fn(mesh, axis_name: str = "q", strategy: str = "quorum",
 
         fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(PS(axis_name), PS(axis_name)),
-            out_specs=PS(axis_name)))
+            out_specs=PS(axis_name),
+            check_vma=kops.shard_map_check_vma(use_kernel)))
         return lambda bodies: fn(bodies, masks)
     if strategy == "atom":
         if use_kernel:

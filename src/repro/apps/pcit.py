@@ -300,8 +300,11 @@ def run_quorum_pcit(X: np.ndarray, mesh, axis_name: str = "q",
         return quorum_pcit_local(xb, mb, schedule=sched, axis_name=axis_name,
                                  use_kernels=use_kernels, mode=mode)
 
+    from ..kernels import ops as kops
     fn = jax.jit(jax.shard_map(body, mesh=mesh,
                                in_specs=(PS(axis_name), PS(axis_name)),
-                               out_specs=(PS(axis_name), PS(axis_name))))
+                               out_specs=(PS(axis_name), PS(axis_name)),
+                               check_vma=kops.shard_map_check_vma(
+                                   use_kernels)))
     corr, keep = fn(Xs, masks)
     return np.asarray(corr), np.asarray(keep)

@@ -50,7 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..kernels.ref import IDX_SENTINEL, NEG_INF, QUERY_METRICS as KNN_METRICS
+from ..kernels.ref import IDX_SENTINEL, NEG_INF, PRECISION
+from ..kernels.ref import QUERY_METRICS as KNN_METRICS
+from ..launch.mesh import make_mesh, shard_rows
 from . import sweep as sweep_mod
 from .scheduler import PairSchedule
 from .sparse import _pair_meta, _pair_score_matrix
@@ -84,7 +86,7 @@ def _item_candidates(bi, bj, metric: str, active, is_self, ga, gb,
     ref.pairwise_topk): (lo-side scores [block, block], lo-side ids,
     hi-side scores, hi-side ids); the hi side is all-sentinel for self
     tiles (one contribution per pair)."""
-    dots = bi @ bj.T                                      # [block, block]
+    dots = jnp.dot(bi, bj.T, precision=PRECISION)         # [block, block]
     if metric == "l2":
         bin2 = jnp.sum(bi * bi, axis=-1)
         bjn2 = jnp.sum(bj * bj, axis=-1)
@@ -347,13 +349,13 @@ def _knn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
+    from ..kernels import ops as kops
     batch_fn = None
     if use_kernel:
         if mode not in ("batched", "auto"):
             raise ValueError(
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched inner step")
-        from ..kernels import ops as kops
         batch_fn = functools.partial(kops.pairwise_topk, topk=topk,
                                      block_rows=block, metric=metric)
 
@@ -364,7 +366,8 @@ def _knn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
 
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)))
+        body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+        check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda xs: fn(xs, mask_table)
 
 
@@ -406,7 +409,7 @@ def knn_graph(corpus, mesh, *, topk: int, axis_name: str = "q",
     x[:N] = corpus
     run = _knn_fn(mesh, axis_name, N, block, int(topk), metric, mode,
                   use_kernel, plc)
-    vals, idx = (np.asarray(a) for a in run(jnp.asarray(x)))
+    vals, idx = (np.asarray(a) for a in run(shard_rows(x, mesh, axis_name)))
     return KnnResult(indices=idx[:N], scores=vals[:N], topk=int(topk))
 
 
@@ -452,7 +455,7 @@ def selfcheck_main(nblocks: int | None = None,
     assert len(devs) >= Pn, f"need {Pn} devices, have {len(devs)}"
     plc = (placement_from_env(Pn) if placement is None
            else resolve_placement(placement, Pn))
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     block, d, topk = 8, 16, 4
     rng = np.random.default_rng(0)
     N = Pn * block - 3          # ragged tail: exercises row validity

@@ -41,8 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..kernels.ref import (FP_REL, IDX_SENTINEL, NEG_INF, QUERY_METRICS,
-                           quant_eps_tile)
+from ..kernels.ref import (FP_REL, IDX_SENTINEL, NEG_INF, PRECISION,
+                           QUERY_METRICS, quant_eps_tile, varying_like)
 from . import env as env_mod
 from . import sweep as sweep_mod
 from .knn import KNN_METRICS, KnnResult, _merge_lists
@@ -274,7 +274,8 @@ def _q_scores_eps(fi, fj, s_lo, s_hi, d_lo, d_hi, l1_i, l1_j, sq_i, sq_j,
     # the single traced home of the quantized tile score + bound:
     # dequantized-dot in f32, exact stored norms for l2 (bit-parity with
     # kernels/ref.py pairwise_threshold_q / pairwise_topk_q)
-    dots = jnp.dot(fi, fj.T, preferred_element_type=jnp.float32) \
+    dots = jnp.dot(fi, fj.T, precision=PRECISION,
+                   preferred_element_type=jnp.float32) \
         * (s_lo * s_hi)
     if metric == "l2":
         scores = (2.0 * dots - sq_j[None, :]) - sq_i[:, None]
@@ -324,7 +325,8 @@ def _q_cand_planes(fi, fj, s_lo, s_hi, sq_i, sq_j, metric: str, active,
                    is_self, ga, gb, nv_lo, nv_hi, block_rows: int):
     # both orientations' masked quantized candidate planes for one tile
     # (the quantized twin of knn._item_candidates; exact stored norms)
-    dots = jnp.dot(fi, fj.T, preferred_element_type=jnp.float32) \
+    dots = jnp.dot(fi, fj.T, precision=PRECISION,
+                   preferred_element_type=jnp.float32) \
         * (s_lo * s_hi)
     if metric == "l2":
         t_lo = (2.0 * dots - sq_j[None, :]) - sq_i[:, None]
@@ -720,13 +722,13 @@ def _qjoin_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
+    from ..kernels import ops as kops
     batch_fn = None
     if use_kernel:
         if mode not in ("batched", "auto"):
             raise ValueError(
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched inner step")
-        from ..kernels import ops as kops
 
         def batch_fn(qb, lo, hi, meta):
             return kops.pairwise_threshold_q(
@@ -746,7 +748,8 @@ def _qjoin_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 6,
-        out_specs=(spec, spec, spec, spec)))
+        out_specs=(spec, spec, spec, spec),
+        check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda leaves: fn(*leaves, mask_table)
 
 
@@ -759,13 +762,13 @@ def _qknn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
+    from ..kernels import ops as kops
     batch_fn = None
     if use_kernel:
         if mode not in ("batched", "auto"):
             raise ValueError(
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched inner step")
-        from ..kernels import ops as kops
 
         def batch_fn(qb, lo, hi, meta):
             return kops.pairwise_topk_q(
@@ -782,7 +785,8 @@ def _qknn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
 
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec, spec)))
+        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec, spec),
+        check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda leaves: fn(*leaves, mask_table)
 
 
@@ -981,7 +985,8 @@ class QuantQueryEmitter(SweepEmitter):
     def _slot_scores(self, fq, scale, sq):
         # [Q, block] dequantized scores of one slot (exact stored norms)
         qn = self.queries
-        s = (qn @ fq.T) * jnp.asarray(scale).reshape(())
+        s = (jnp.dot(qn, fq.T, precision=PRECISION)
+             * jnp.asarray(scale).reshape(()))
         if self.metric == "l2":
             s = ((2.0 * s - sq[None, :])
                  - jnp.sum(qn * qn, axis=-1)[:, None])
@@ -997,7 +1002,8 @@ class QuantQueryEmitter(SweepEmitter):
         from .sweep import topk_by_score
         fq = quorum.q.astype(jnp.float32)
         k, block = fq.shape[0], fq.shape[1]
-        s = jnp.einsum("qd,sbd->qsb", self.queries, fq) \
+        s = jnp.einsum("qd,sbd->qsb", self.queries, fq,
+                       precision=PRECISION) \
             * quorum.scale.reshape(-1)[None, :, None]
         if self.metric == "l2":
             s = ((2.0 * s - quorum.sq[None])
@@ -1015,10 +1021,13 @@ class QuantQueryEmitter(SweepEmitter):
                              flat_idx.reshape(Q, k * block), self.topk)
 
     def scan_init(self):
-        """Sentinel-filled [Q, topk] running lists."""
+        """Sentinel-filled [Q, topk] running lists, varying like the
+        per-device mask the body folds in."""
         Q = self.queries.shape[0]
-        return (jnp.full((Q, self.topk), NEG_INF, jnp.float32),
-                jnp.full((Q, self.topk), IDX_SENTINEL, jnp.int32))
+        return varying_like(
+            (jnp.full((Q, self.topk), NEG_INF, jnp.float32),
+             jnp.full((Q, self.topk), IDX_SENTINEL, jnp.int32)),
+            self.queries, self.mask, self.gidx)
 
     def scan_items(self):
         """(slot, mask row, global-id row) per resident slot."""
@@ -1308,7 +1317,8 @@ def selfcheck_main(nblocks: int | None = None, modes=None,
         modes = tuple(ENGINE_MODES) + ("kernel",)
     plc = (placement_from_env(Pn) if placement is None
            else resolve_placement(placement, Pn))
-    mesh = jax.make_mesh((Pn,), ("q",), devices=jax.devices()[:Pn])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((Pn,), ("q",), devices=jax.devices()[:Pn])
 
     block, d, topk = 8, 16, 4
     N = Pn * block - 3

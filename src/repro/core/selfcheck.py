@@ -60,7 +60,8 @@ def main(nblocks: int | None = None,
     assert len(devs) >= Pn, f"need {Pn} devices, have {len(devs)}"
     plc = (placement_from_env(Pn) if placement is None
            else resolve_placement(placement, Pn))
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     sched = None if plc.full else plc.schedule()
     block = 8
     rng = np.random.default_rng(0)

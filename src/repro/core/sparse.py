@@ -56,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..kernels.ref import IDX_SENTINEL, NEG_INF
+from ..kernels.ref import IDX_SENTINEL, NEG_INF, PRECISION
+from ..launch.mesh import make_mesh, shard_rows
 from ..obs import trace as obs_trace
 from . import env as env_mod
 from . import sweep as sweep_mod
@@ -170,7 +171,7 @@ def _tile_scores(bi: jax.Array, bj: jax.Array, metric: str) -> jax.Array:
     formula as the serving engine and the fused kernels, so float
     rounding (and therefore threshold membership) agrees across paths.
     """
-    dot = bi @ bj.T
+    dot = jnp.dot(bi, bj.T, precision=PRECISION)
     if metric == "dot":
         return dot
     return (2.0 * dot - jnp.sum(bj * bj, axis=-1)[None, :]
@@ -248,8 +249,10 @@ def _pair_meta(schedule: PairSchedule, axis_name: str, block: int,
     i = lax.axis_index(axis_name)
     shifts = jnp.asarray(schedule.shifts, jnp.int32)
     gblocks = (i + shifts) % P                                    # [k]
-    lo = jnp.asarray(schedule.pair_slots[:, 0])
-    hi = jnp.asarray(schedule.pair_slots[:, 1])
+    # slot ids stay host constants: the fused top-k kernels build their
+    # item table from them (kernels/pairwise_topk.py directed_items)
+    lo = schedule.pair_slots[:, 0].astype(np.int32)
+    hi = schedule.pair_slots[:, 1].astype(np.int32)
     ga = gblocks[lo]
     gb = gblocks[hi]
     if n_valid is None:
@@ -578,13 +581,13 @@ def _join_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
+    from ..kernels import ops as kops
     batch_fn = None
     if use_kernel:
         if mode not in ("batched", "auto"):
             raise ValueError(
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched inner step")
-        from ..kernels import ops as kops
         batch_fn = functools.partial(
             kops.pairwise_threshold, threshold=threshold, capacity=capacity,
             block_rows=block, metric=metric)
@@ -599,7 +602,8 @@ def _join_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec),
-        out_specs=(spec, spec, spec, spec)))
+        out_specs=(spec, spec, spec, spec),
+        check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda xs: fn(xs, mask_table)
 
 
@@ -654,7 +658,7 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
     block = -(-N // P)
     x = np.zeros((P * block, d), np.float32)
     x[:N] = corpus
-    xs = jnp.asarray(x)
+    xs = shard_rows(x, mesh, axis_name)
     sched = plc.schedule()
     n_cand = sched.n_pairs * block * block
     cap = int(capacity) if capacity is not None else default_capacity(n_cand)
@@ -816,7 +820,7 @@ def selfcheck_main(nblocks: int | None = None,
     assert len(devs) >= Pn, f"need {Pn} devices, have {len(devs)}"
     plc = (placement_from_env(Pn) if placement is None
            else resolve_placement(placement, Pn))
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     block, d = 8, 16
     rng = np.random.default_rng(0)
     N = Pn * block - 3          # ragged tail: exercises row validity

@@ -309,13 +309,10 @@ def pair_mask_table(schedule: PairSchedule) -> np.ndarray:
 
 
 def mark_varying(x: jax.Array, axis_name: str) -> jax.Array:
-    """Mark x as varying over the quorum axis (jax >= 0.7 VMA tracking;
-    the shard_map plumbing every engine-internal constant goes through —
+    """Mark x as varying over the quorum axis (shard_map's varying-axis
+    typing; the plumbing every engine-internal constant goes through —
     DESIGN.md section 2)."""
-    try:
-        return lax.pcast(x, axis_name, to="varying")
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        return x
+    return lax.pcast(x, axis_name, to="varying")
 
 
 # ---------------------------------------------------------------------------

@@ -1,28 +1,17 @@
 """Jit'd public entry points for the Pallas kernels (DESIGN.md section 6).
 
-On TPU backends the kernels run compiled; elsewhere (CPU tests, smoke) they
-run in interpret mode, which executes the kernel body in Python with
-identical block semantics — the per-kernel allclose sweeps in
-tests/test_kernels.py validate every (shape, dtype) cell against ref.py.
-
-The three engine ``batch_fn`` hooks (:func:`pairwise_batch_forces`,
-:func:`query_topk`, :func:`pairwise_threshold`) additionally degrade
-gracefully when the Pallas lowering itself is unavailable on the running
-backend (an ``ImportError``/``NotImplementedError`` from the kernel
-machinery — e.g. a jax build without Pallas support): they fall back to
-the bit-parity jnp oracle in ref.py with a one-time warning, so an engine
-configured with ``use_kernel=True`` stays correct everywhere.  Numeric
-kernel bugs are *not* masked — those surface as value mismatches in the
-kernel sweeps, never as these exception types.  Both dispatch layers
-(interpret-vs-compiled via :func:`_interpret`, kernel-absent via
-:func:`_call_with_fallback`) are covered directly in
-tests/test_ops_dispatch.py.
+On TPU the kernels run compiled; on the CPU backend (tests) they run in
+interpret mode, which executes the kernel body in Python with identical
+block semantics — the per-kernel sweeps in tests/test_kernels.py validate
+every (shape, dtype) cell against ref.py.  There is no fallback to the
+oracles: a kernel that cannot lower raises, so a run that asked for the
+kernel never silently measures the jnp path instead.  The dispatch is
+covered directly in tests/test_ops_dispatch.py.
 """
 
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -35,41 +24,34 @@ from .pcit_filter import pcit_filter_pallas
 
 
 def _interpret() -> bool:
-    """True when the Pallas kernels should run in interpret mode (any
-    backend without a Mosaic TPU compiler — CPU tests, GPU smoke).  The
-    dispatch every jit'd entry point below routes through (DESIGN.md
-    section 6)."""
-    return jax.default_backend() != "tpu"
+    """True when the Pallas kernels should run in interpret mode: on the
+    CPU backend only (tests).  On TPU, and on any other backend, they
+    compile — the dispatch every jit'd entry point below routes through
+    (DESIGN.md section 6)."""
+    return jax.default_backend() == "cpu"
 
 
-# hooks that already warned about a missing kernel this process — the
-# warn-once keyset (the core/env.py ``_seen_env_keys`` pattern, keyed on
-# the hook name so it survives ``warnings.simplefilter('always')``);
-# tests reset it between cases (tests/test_ops_dispatch.py)
-_warned_fallback: set = set()
+def shard_map_check_vma(use_kernel: bool) -> bool:
+    """``check_vma`` for an engine ``shard_map`` whose body may launch
+    these kernels.  The Pallas HLO interpreter that runs them on the CPU
+    evaluates a kernel body without shard_map's varying-axis typing and
+    is refused under it, so on CPU a kernel-carrying program runs
+    unchecked; on TPU the kernels compile and the typing stays checked
+    (tests/test_tpu_compile.py compiles the k-NN sweep that way).
+    Programs without a kernel are always checked."""
+    return not (use_kernel and _interpret())
 
 
-def _call_with_fallback(kernel_thunk, ref_thunk, name: str):
-    """Run a Pallas engine-hook kernel, degrading to its ref.py oracle.
-
-    Only ``ImportError`` / ``NotImplementedError`` — the "kernel is
-    absent on this backend" signals raised at trace time by the Pallas
-    machinery — trigger the fallback; anything else (shape errors,
-    numeric asserts) propagates so real kernel bugs stay visible.  The
-    warning fires once per hook per process (every retrace of a hot
-    engine loop hits this path, and a per-call warning floods the log
-    without adding information).
-    """
-    try:
-        return kernel_thunk()
-    except (ImportError, NotImplementedError) as e:
-        if name not in _warned_fallback:
-            _warned_fallback.add(name)
-            warnings.warn(
-                f"Pallas kernel {name!r} unavailable on this backend "
-                f"({type(e).__name__}: {e}); falling back to the jnp "
-                "reference implementation", RuntimeWarning, stacklevel=2)
-        return ref_thunk()
+def _join_kernel_lowers(name: str) -> None:
+    """The fused join kernels compact hits with an in-tile cumsum and a
+    [block^2, capacity] one-hot matmul: Mosaic has no cumsum lowering and
+    the one-hot overruns VMEM at any real block, so on a compiling
+    backend they raise here with the reason (ROADMAP S4)."""
+    if jax.default_backend() != "cpu":
+        raise NotImplementedError(
+            f"{name}: the fused join kernel does not lower for "
+            f"{jax.default_backend()} (in-tile cumsum compaction, "
+            "ROADMAP S4); run the join with use_kernel=False")
 
 
 def _pad_to(x, multiple, axis):
@@ -138,18 +120,13 @@ def pairwise_batch_forces(quorum, lo, hi, wi, wj, *, softening=1e-2):
 
     Pads block up to the 8-sublane multiple with zero-mass bodies at the
     origin — exact, since zero mass contributes zero force either way —
-    and slices back.  Falls back to ref.pairwise_batch_forces when the
-    Pallas lowering is absent (see module docstring).
+    and slices back.
     """
     q, block = _pad_to(quorum, 8, 1)
     w = jnp.stack([jnp.asarray(wi, jnp.float32),
                    jnp.asarray(wj, jnp.float32)], axis=1)
-    out = _call_with_fallback(
-        lambda: pairwise_batch_pallas(q, lo, hi, w, softening=softening,
-                                      interpret=_interpret()),
-        lambda: ref.pairwise_batch_forces(q, lo, hi, w[:, 0], w[:, 1],
-                                          softening=softening),
-        "pairwise_batch_forces")
+    out = pairwise_batch_pallas(q, lo, hi, w, softening=softening,
+                                interpret=_interpret())
     return out[:, :block]
 
 
@@ -165,17 +142,11 @@ def query_topk(stack, queries, mask, gidx, *, topk, metric="dot"):
 
     Pads Q up to the 8-sublane multiple with zero queries and slices the
     padded rows back off — exact, the extra rows never leave the wrapper.
-    Falls back to ref.query_topk when the Pallas lowering is absent (see
-    module docstring).
     """
     from .query_score import query_topk_pallas
     q, Q = _pad_to(queries, 8, 0)
-    vals, idx = _call_with_fallback(
-        lambda: query_topk_pallas(stack, q, mask, gidx, topk=topk,
-                                  metric=metric, interpret=_interpret()),
-        lambda: ref.query_topk(stack, q, mask, gidx, topk=topk,
-                               metric=metric),
-        "query_topk")
+    vals, idx = query_topk_pallas(stack, q, mask, gidx, topk=topk,
+                                  metric=metric, interpret=_interpret())
     return vals[:Q], idx[:Q]
 
 
@@ -197,53 +168,41 @@ def pairwise_threshold(quorum, lo, hi, meta, *, threshold, capacity,
     Pads block rows up to the 8-sublane multiple with zero rows — exact,
     the valid-row bounds in ``meta`` already reject them — and capacity
     up to the 128-lane multiple, slicing back (the dropped tail keeps the
-    first-``capacity`` prefix semantics).  Falls back to
-    ref.pairwise_threshold when the Pallas lowering is absent (see
-    module docstring).
+    first-``capacity`` prefix semantics).  Raises on TPU (see
+    :func:`_join_kernel_lowers`).
     """
     from .pairwise_threshold import pairwise_threshold_pallas
+    _join_kernel_lowers("pairwise_threshold")
     q, _ = _pad_to(quorum, 8, 1)
     capp = -(-capacity // 128) * 128
-    vals, gi, gj, count = _call_with_fallback(
-        lambda: pairwise_threshold_pallas(
-            q, lo, hi, meta, threshold=threshold, capacity=capp,
-            block_rows=block_rows, metric=metric, interpret=_interpret()),
-        lambda: ref.pairwise_threshold(
-            q, lo, hi, meta, threshold=threshold, capacity=capp,
-            block_rows=block_rows, metric=metric),
-        "pairwise_threshold")
+    vals, gi, gj, count = pairwise_threshold_pallas(
+        q, lo, hi, meta, threshold=threshold, capacity=capp,
+        block_rows=block_rows, metric=metric, interpret=_interpret())
     return (vals[:capacity], gi[:capacity], gj[:capacity],
             count.reshape(()))
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "block_rows", "metric"))
 def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot"):
     """Fused pair-scoring top-k step for the k-NN graph engine's
     ``batch_fn`` hook (core/knn.py; DESIGN.md section 12.3).
 
-    quorum: [k, block, d]; lo/hi: [n_pairs] slot ids; meta: [n_pairs, 6]
-    int32 ``(active, is_self, ga, gb, nv_lo, nv_hi)``.  ``topk`` is the
-    per-row neighbor-list length, ``block_rows`` the global block stride
-    for row-id math.  Returns the per-slot running top-k
+    quorum: [k, block, d]; lo/hi: [n_pairs] concrete slot ids (the
+    schedule's — the kernel's item table is built from them on the host,
+    so this hook is not jitted itself and runs inside the caller's jit);
+    meta: [n_pairs, 6] int32 ``(active, is_self, ga, gb, nv_lo, nv_hi)``.
+    ``topk`` is the per-row neighbor-list length, ``block_rows`` the
+    global block stride for row-id math.  Returns the per-slot running top-k
     ``(vals f32 [k, block, topk], idx i32 [k, block, topk])`` under the
     engine's (-score, index) order — bit-parity with ref.pairwise_topk.
 
-    Pads block rows up to the 8-sublane multiple with zero rows — exact,
-    the valid-row bounds in ``meta`` already reject them as candidates
-    and the padded rows' own lists are sliced back off.  Falls back to
-    ref.pairwise_topk when the Pallas lowering is absent (see module
-    docstring).
+    The kernel zero-pads block rows up to its tile multiple — exact, the
+    valid-row bounds in ``meta`` reject them as candidates and the padded
+    rows' own lists are sliced back off.
     """
     from .pairwise_topk import pairwise_topk_pallas
-    q, block = _pad_to(quorum, 8, 1)
-    vals, idx = _call_with_fallback(
-        lambda: pairwise_topk_pallas(q, lo, hi, meta, topk=topk,
-                                     block_rows=block_rows, metric=metric,
-                                     interpret=_interpret()),
-        lambda: ref.pairwise_topk(q, lo, hi, meta, topk=topk,
-                                  block_rows=block_rows, metric=metric),
-        "pairwise_topk")
-    return vals[:, :block], idx[:, :block]
+    return pairwise_topk_pallas(quorum, lo, hi, meta, topk=topk,
+                                block_rows=block_rows, metric=metric,
+                                interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "capacity",
@@ -263,29 +222,22 @@ def pairwise_threshold_q(q, sd, l1, sq, lo, hi, meta, *, threshold,
     Pads block rows up to the 8-sublane multiple with zero rows (zero
     quantized values and zero norms — the valid-row bounds in ``meta``
     already reject them) and capacity up to the 128-lane multiple,
-    slicing back.  Falls back to ref.pairwise_threshold_q when the
-    Pallas lowering is absent (see module docstring).
+    slicing back.  Raises on TPU (see :func:`_join_kernel_lowers`).
     """
     from .pairwise_batch_q import pairwise_threshold_q_pallas
+    _join_kernel_lowers("pairwise_threshold_q")
     qp, _ = _pad_to(q, 8, 1)
     l1p, _ = _pad_to(l1, 8, 1)
     sqp, _ = _pad_to(sq, 8, 1)
     capp = -(-capacity // 128) * 128
-    vals, gi, gj, count = _call_with_fallback(
-        lambda: pairwise_threshold_q_pallas(
-            qp, sd, l1p, sqp, lo, hi, meta, threshold=threshold,
-            capacity=capp, block_rows=block_rows, metric=metric,
-            interpret=_interpret()),
-        lambda: ref.pairwise_threshold_q(
-            qp, sd[:, 0], sd[:, 1], l1p, sqp, lo, hi, meta,
-            threshold=threshold, capacity=capp, block_rows=block_rows,
-            metric=metric),
-        "pairwise_threshold_q")
+    vals, gi, gj, count = pairwise_threshold_q_pallas(
+        qp, sd, l1p, sqp, lo, hi, meta, threshold=threshold,
+        capacity=capp, block_rows=block_rows, metric=metric,
+        interpret=_interpret())
     return (vals[:capacity], gi[:capacity], gj[:capacity],
             count.reshape(()))
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "block_rows", "metric"))
 def pairwise_topk_q(q, sd, sq, lo, hi, meta, *, topk, block_rows,
                     metric="dot"):
     """Quantized pair-scoring top-k step for the quant engine's
@@ -297,24 +249,15 @@ def pairwise_topk_q(q, sd, sq, lo, hi, meta, *, topk, block_rows,
     Returns the per-slot running *quantized* top-k — the host certifies
     and rescores the lists exactly.
 
-    Pads block rows up to the 8-sublane multiple with zero rows (zero
+    The kernel zero-pads block rows up to its tile multiple (zero
     quantized values and zero norms — the valid-row bounds in ``meta``
-    already reject them as candidates and padded rows' own lists are
-    sliced back off).  Falls back to ref.pairwise_topk_q when the
-    Pallas lowering is absent (see module docstring).
+    reject them as candidates and padded rows' own lists are sliced back
+    off).
     """
     from .pairwise_batch_q import pairwise_topk_q_pallas
-    qp, block = _pad_to(q, 8, 1)
-    sqp, _ = _pad_to(sq, 8, 1)
-    vals, idx = _call_with_fallback(
-        lambda: pairwise_topk_q_pallas(
-            qp, sd, sqp, lo, hi, meta, topk=topk, block_rows=block_rows,
-            metric=metric, interpret=_interpret()),
-        lambda: ref.pairwise_topk_q(
-            qp, sd[:, 0], sqp, lo, hi, meta, topk=topk,
-            block_rows=block_rows, metric=metric),
-        "pairwise_topk_q")
-    return vals[:, :block], idx[:, :block]
+    return pairwise_topk_q_pallas(q, sd, sq, lo, hi, meta, topk=topk,
+                                  block_rows=block_rows, metric=metric,
+                                  interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk"))

@@ -8,11 +8,15 @@ the whole step for the n-body-shaped ``pair_fn``:
 
   * slot gather — the scalar-prefetched pair slot ids index the quorum
     operand directly in the BlockSpec index maps, so each grid step DMAs
-    exactly the two [block, 4] body blocks it interacts,
-  * pair interaction — the [block, block] force tile lives only in VMEM,
-  * segment reduction — both sides accumulate straight into a [k, block, 3]
-    VMEM accumulator at their slot rows; the output is written once at the
-    final grid step.
+    exactly the two body blocks it interacts: the lo block as [block, 4]
+    columns (bodies on sublanes) and the hi block as [4, block] rows
+    (bodies on lanes),
+  * pair interaction — one [block, block] plane per coordinate lives only
+    in VMEM (no 3-wide minor axis, which Mosaic cannot tile),
+  * segment reduction — both sides accumulate lane-dense into a
+    [k, 3, block] VMEM accumulator at their slot rows: the hi side by a
+    sublane sum, the lo side by a ones-row matmul that lands the row sums
+    on lanes; the output is written once at the final grid step.
 
 Layout notes (v5e): the feature dims (4-wide bodies in, 3-wide forces out)
 sit far below the 128-lane tile, so on hardware this kernel is VPU/DMA-bound
@@ -29,8 +33,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import PRECISION, out_struct
 
 DEFAULT_SOFTENING = 1e-2
 
@@ -43,26 +50,23 @@ def _nbody_batch_kernel(lo_ref, hi_ref, x_lo_ref, x_hi_ref, w_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bi = x_lo_ref[0]                                     # [block, 4]
-    bj = x_hi_ref[0]
-    pi, mi = bi[:, :3], bi[:, 3]
-    pj, mj = bj[:, :3], bj[:, 3]
-    d = pj[None, :, :] - pi[:, None, :]                  # [block, block, 3]
-    r2 = jnp.sum(d * d, axis=-1) + softening
-    inv_r3 = jax.lax.rsqrt(r2) / r2
-    w = (mi[:, None] * mj[None, :] * inv_r3)[..., None]
-    f_ij = w * d                                         # force ON i FROM j
-    f_i = jnp.sum(f_ij, axis=1)                          # [block, 3]
-    f_j = -jnp.sum(f_ij, axis=0)
-
-    lo = lo_ref[p]
-    hi = hi_ref[p]
-    wi = w_ref[0, 0]
-    wj = w_ref[0, 1]
-    cur = pl.load(acc_ref, (pl.dslice(lo, 1),))
-    pl.store(acc_ref, (pl.dslice(lo, 1),), cur + wi * f_i[None])
-    cur = pl.load(acc_ref, (pl.dslice(hi, 1),))
-    pl.store(acc_ref, (pl.dslice(hi, 1),), cur + wj * f_j[None])
+    bi = x_lo_ref[...]                                   # [block, 4]
+    bj = x_hi_ref[...]                                   # [4, block]
+    d = [bj[c:c + 1, :] - bi[:, c:c + 1] for c in range(3)]
+    r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2] + softening
+    inv_r3 = lax.rsqrt(r2) / r2
+    w = bi[:, 3:4] * bj[3:4, :] * inv_r3                 # [block, block]
+    ones = jnp.ones((1, w.shape[1]), jnp.float32)
+    wi = w_ref[p, 0]
+    wj = w_ref[p, 1]
+    for c in range(3):
+        f = w * d[c]                                     # force ON i FROM j
+        f_i = lax.dot_general(ones, f, (((1,), (1,)), ((), ())),
+                              precision=PRECISION,
+                              preferred_element_type=jnp.float32)  # [1, blk]
+        f_j = -jnp.sum(f, axis=0, keepdims=True)                    # [1, blk]
+        acc_ref[lo_ref[p], c:c + 1, :] += wi * f_i
+        acc_ref[hi_ref[p], c:c + 1, :] += wj * f_j
 
     @pl.when(p == n_pairs - 1)
     def _done():
@@ -72,7 +76,7 @@ def _nbody_batch_kernel(lo_ref, hi_ref, x_lo_ref, x_hi_ref, w_ref, o_ref,
 def pairwise_batch_pallas(quorum: jax.Array, lo: jax.Array, hi: jax.Array,
                           w: jax.Array, *,
                           softening: float = DEFAULT_SOFTENING,
-                          interpret: bool = False) -> jax.Array:
+                          interpret=False) -> jax.Array:
     """quorum: [k, block, 4] body blocks (x, y, z, mass); lo/hi: [n_pairs]
     int32 slot ids; w: [n_pairs, 2] float32 (out_i, out_j) pair weights —
     wj = 0 for the self pair (count once) and masked d = P/2 orbits.
@@ -87,18 +91,20 @@ def pairwise_batch_pallas(quorum: jax.Array, lo: jax.Array, hi: jax.Array,
         num_scalar_prefetch=2,                 # lo, hi drive the index maps
         grid=(n_pairs,),
         in_specs=[
-            pl.BlockSpec((1, block, 4), lambda p, lo, hi: (lo[p], 0, 0)),
-            pl.BlockSpec((1, block, 4), lambda p, lo, hi: (hi[p], 0, 0)),
-            pl.BlockSpec((1, 2), lambda p, lo, hi: (p, 0)),
+            pl.BlockSpec((None, block, 4), lambda p, lo, hi: (lo[p], 0, 0)),
+            pl.BlockSpec((None, 4, block), lambda p, lo, hi: (hi[p], 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # w: [n_pairs, 2]
         ],
-        out_specs=pl.BlockSpec((k, block, 3), lambda p, lo, hi: (0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((k, block, 3), jnp.float32)],
+        out_specs=pl.BlockSpec((k, 3, block), lambda p, lo, hi: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((k, 3, block), jnp.float32)],
     )
-    return pl.pallas_call(
+    ins = (jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+           quorum, jnp.swapaxes(quorum, 1, 2), jnp.asarray(w, jnp.float32))
+    out = pl.pallas_call(
         functools.partial(_nbody_batch_kernel, n_pairs=n_pairs,
                           softening=softening),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, block, 3), jnp.float32),
+        out_shape=out_struct((k, 3, block), jnp.float32, *ins),
         interpret=interpret,
-    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-      quorum, quorum, jnp.asarray(w, jnp.float32))
+    )(*ins)
+    return jnp.swapaxes(out, 1, 2)

@@ -41,18 +41,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pairwise_topk import _merge_rows
+from .pairwise_topk import directed_topk_call
 from .ref import IDX_SENTINEL as _IDX_SENTINEL
-from .ref import FP_REL, NEG_INF, QUERY_METRICS
+from .ref import FP_REL, NEG_INF, PRECISION, QUERY_METRICS, out_struct
 
 IDX_SENTINEL = int(_IDX_SENTINEL)
 
 
 def _eps_tile(d_lo, d_hi, l1_lo, l1_hi, *, dim: int, metric: str):
-    # expression order matches ref.quant_eps_tile for bit parity
-    eps = (d_lo * l1_hi[None, :] + d_hi * l1_lo[:, None]
+    # l1_lo: [block, 1] column, l1_hi: [1, block] row; expression order
+    # matches ref.quant_eps_tile for bit parity
+    eps = (d_lo * l1_hi + d_hi * l1_lo
            + 3.0 * dim * d_lo * d_hi
-           + FP_REL * (l1_lo[:, None] * l1_hi[None, :] + 1.0))
+           + FP_REL * (l1_lo * l1_hi + 1.0))
     if metric == "l2":
         eps = 2.0 * eps
     return eps
@@ -79,11 +80,10 @@ def _threshold_q_kernel(lo_ref, hi_ref, meta_ref, q_lo_ref, q_hi_ref,
         bj = q_hi_ref[0].astype(jnp.float32)
         s_lo = sd_ref[lo_ref[p], 0]
         s_hi = sd_ref[hi_ref[p], 0]
-        dots = jnp.dot(bi, bj.T,
+        dots = jnp.dot(bi, bj.T, precision=PRECISION,
                        preferred_element_type=jnp.float32) * (s_lo * s_hi)
         if metric == "l2":  # exact norms: oracle parity + 2x-dot bound
-            scores = (2.0 * dots - sq_hi_ref[0][None, :]) \
-                - sq_lo_ref[0][:, None]
+            scores = (2.0 * dots - sq_hi_ref[0]) - sq_lo_ref[0]
         else:
             scores = dots
         eps = _eps_tile(sd_ref[lo_ref[p], 1], sd_ref[hi_ref[p], 1],
@@ -159,10 +159,10 @@ def pairwise_threshold_q_pallas(q: jax.Array, sd: jax.Array,
             pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (lo[p], 0, 0)),
             pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (hi[p], 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),        # sd: [k, 2]
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (lo[p], 0)),
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (hi[p], 0)),
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (lo[p], 0)),
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (hi[p], 0)),
+            pl.BlockSpec((1, block, 1), lambda p, lo, hi, meta: (lo[p], 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda p, lo, hi, meta: (hi[p], 0, 0)),
+            pl.BlockSpec((1, block, 1), lambda p, lo, hi, meta: (lo[p], 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda p, lo, hi, meta: (hi[p], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, capacity), lambda p, lo, hi, meta: (0, 0)),
@@ -175,77 +175,24 @@ def pairwise_threshold_q_pallas(q: jax.Array, sd: jax.Array,
                         pltpu.VMEM((1, capacity), jnp.float32),
                         pltpu.SMEM((1, 1), jnp.int32)],
     )
+    l1 = jnp.asarray(l1, jnp.float32)
+    sq = jnp.asarray(sq, jnp.float32)
+    ins = (jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+           jnp.asarray(meta, jnp.int32), q, q, jnp.asarray(sd, jnp.float32),
+           l1[:, :, None], l1[:, None, :], sq[:, :, None], sq[:, None, :])
     vals, gi, gj, count = pl.pallas_call(
         functools.partial(_threshold_q_kernel, n_pairs=n_pairs,
                           block_rows=block_rows, capacity=capacity,
                           threshold=float(threshold), metric=metric,
                           dim=d),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((1, capacity), jnp.float32),
-                   jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+        out_shape=[out_struct((1, capacity), jnp.float32, *ins),
+                   out_struct((1, capacity), jnp.int32, *ins),
+                   out_struct((1, capacity), jnp.int32, *ins),
+                   out_struct((1, 1), jnp.int32, *ins)],
         interpret=interpret,
-    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-      jnp.asarray(meta, jnp.int32), q, q,
-      jnp.asarray(sd, jnp.float32),
-      jnp.asarray(l1, jnp.float32), jnp.asarray(l1, jnp.float32),
-      jnp.asarray(sq, jnp.float32), jnp.asarray(sq, jnp.float32))
+    )(*ins)
     return vals[0], gi[0], gj[0], count[0, 0]
-
-
-def _pairwise_topk_q_kernel(lo_ref, hi_ref, meta_ref, q_lo_ref, q_hi_ref,
-                            sd_ref, sq_lo_ref, sq_hi_ref,
-                            ov_ref, oi_ref, vacc_ref, iacc_ref, *,
-                            n_pairs: int, block_rows: int, topk: int,
-                            metric: str):
-    p = pl.program_id(0)
-
-    @pl.when(p == 0)
-    def _init():
-        vacc_ref[...] = jnp.full_like(vacc_ref, NEG_INF)
-        iacc_ref[...] = jnp.full_like(iacc_ref, IDX_SENTINEL)
-
-    @pl.when(meta_ref[p, 0] == 1)
-    def _tile():
-        bi = q_lo_ref[0].astype(jnp.float32)              # [block, d]
-        bj = q_hi_ref[0].astype(jnp.float32)
-        blk = bi.shape[0]
-        dots = jnp.dot(bi, bj.T, preferred_element_type=jnp.float32) \
-            * (sd_ref[lo_ref[p], 0] * sd_ref[hi_ref[p], 0])
-        if metric == "l2":  # exact-norm orientation order: oracle parity
-            bin2 = sq_lo_ref[0]
-            bjn2 = sq_hi_ref[0]
-            t_lo = (2.0 * dots - bjn2[None, :]) - bin2[:, None]
-            t_hi = (2.0 * dots - bin2[:, None]) - bjn2[None, :]
-        else:
-            t_lo = t_hi = dots
-        is_self = meta_ref[p, 1]
-        r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-        s = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-        # lo side: rows of bi receive bj's valid rows as candidates
-        keep = (s < meta_ref[p, 5]) & jnp.where(is_self == 1, r != s, True)
-        cand_v = jnp.where(keep, t_lo, NEG_INF)
-        cand_i = jnp.where(keep, meta_ref[p, 3] * block_rows + s,
-                           IDX_SENTINEL)
-        _merge_rows(vacc_ref, iacc_ref, lo_ref[p] * blk, blk, topk,
-                    cand_v, cand_i)
-
-        # hi side (transposed orientation; self tiles contribute once)
-        @pl.when(is_self == 0)
-        def _hi_side():
-            keep_t = (r < meta_ref[p, 4]).T
-            cv_t = jnp.where(keep_t, t_hi.T, NEG_INF)
-            ci_t = jnp.where(keep_t,
-                             (meta_ref[p, 2] * block_rows + r).T,
-                             IDX_SENTINEL)
-            _merge_rows(vacc_ref, iacc_ref, hi_ref[p] * blk, blk, topk,
-                        cv_t, ci_t)
-
-    @pl.when(p == n_pairs - 1)
-    def _done():
-        ov_ref[...] = vacc_ref[...]
-        oi_ref[...] = iacc_ref[...]
 
 
 def pairwise_topk_q_pallas(q: jax.Array, sd: jax.Array, sq: jax.Array,
@@ -257,46 +204,13 @@ def pairwise_topk_q_pallas(q: jax.Array, sd: jax.Array, sq: jax.Array,
     exact f32 squared row norms (l2); lo/hi: [n_pairs] int32 slot ids;
     meta: [n_pairs, 6] int32 ``(active, is_self, ga, gb, nv_lo, nv_hi)``
     (see ref.pairwise_topk_q, the bit-parity oracle; DESIGN.md section
-    17.3).  Returns the per-slot running quantized top-k after all
-    tiles: ``(vals f32 [k, block, topk], idx i32 [k, block, topk])``.
+    17.3).  Runs the tiled directed grid of kernels/pairwise_topk.py with
+    the dequant epilogue.  Returns the per-slot running quantized top-k
+    after all tiles: ``(vals f32 [k, block, topk], idx i32 [k, block,
+    topk])``.
     """
-    if metric not in QUERY_METRICS:
-        raise ValueError(f"metric must be one of {QUERY_METRICS}, "
-                         f"got {metric!r}")
-    k, block, d = q.shape
-    n_pairs = lo.shape[0]
-    assert hi.shape == (n_pairs,) and meta.shape == (n_pairs, 6), \
-        (hi.shape, meta.shape)
-    assert sd.shape == (k, 2) and sq.shape == (k, block), \
-        (sd.shape, sq.shape)
-    assert block >= block_rows, (block, block_rows)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # lo, hi, meta drive the tiles
-        grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (lo[p], 0, 0)),
-            pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (hi[p], 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),        # sd: [k, 2]
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (lo[p], 0)),
-            pl.BlockSpec((1, block), lambda p, lo, hi, meta: (hi[p], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((k * block, topk), lambda p, lo, hi, meta: (0, 0)),
-            pl.BlockSpec((k * block, topk), lambda p, lo, hi, meta: (0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((k * block, topk), jnp.float32),
-                        pltpu.VMEM((k * block, topk), jnp.int32)],
-    )
-    vals, idx = pl.pallas_call(
-        functools.partial(_pairwise_topk_q_kernel, n_pairs=n_pairs,
-                          block_rows=block_rows, topk=topk, metric=metric),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((k * block, topk), jnp.float32),
-                   jax.ShapeDtypeStruct((k * block, topk), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-      jnp.asarray(meta, jnp.int32), q, q,
-      jnp.asarray(sd, jnp.float32),
-      jnp.asarray(sq, jnp.float32), jnp.asarray(sq, jnp.float32))
-    return vals.reshape(k, block, topk), idx.reshape(k, block, topk)
+    k = q.shape[0]
+    assert sd.shape == (k, 2), sd.shape
+    return directed_topk_call(q, sq, lo, hi, meta, topk=topk,
+                              block_rows=block_rows, metric=metric, sd=sd,
+                              interpret=interpret)

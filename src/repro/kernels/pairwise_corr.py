@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ref import out_struct
+
 DEFAULT_BM = 256
 DEFAULT_BN = 256
 DEFAULT_BK = 512
@@ -57,7 +59,7 @@ def pairwise_corr_pallas(xs_i: jax.Array, xs_j: jax.Array, *,
             pl.BlockSpec((bn, bk), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        out_shape=out_struct((M, N), jnp.float32, xs_i, xs_j),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(xs_i, xs_j)

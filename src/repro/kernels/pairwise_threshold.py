@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ref import IDX_SENTINEL as _IDX_SENTINEL
-from .ref import NEG_INF
+from .ref import NEG_INF, PRECISION, out_struct
 
 IDX_SENTINEL = int(_IDX_SENTINEL)
 
@@ -65,7 +65,8 @@ def _threshold_kernel(lo_ref, hi_ref, meta_ref, x_lo_ref, x_hi_ref,
     def _tile():
         bi = x_lo_ref[0]                                  # [block, d]
         bj = x_hi_ref[0]
-        dot = jnp.dot(bi, bj.T, preferred_element_type=jnp.float32)
+        dot = jnp.dot(bi, bj.T, precision=PRECISION,
+                      preferred_element_type=jnp.float32)
         if metric == "l2":  # same formula as engine/oracle: bit parity
             scores = (2.0 * dot - jnp.sum(bj * bj, axis=-1)[None, :]
                       - jnp.sum(bi * bi, axis=-1)[:, None])
@@ -147,17 +148,18 @@ def pairwise_threshold_pallas(quorum: jax.Array, lo: jax.Array,
                         pltpu.VMEM((1, capacity), jnp.float32),
                         pltpu.SMEM((1, 1), jnp.int32)],
     )
+    ins = (jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+           jnp.asarray(meta, jnp.int32), quorum.astype(jnp.float32),
+           quorum.astype(jnp.float32))
     vals, gi, gj, count = pl.pallas_call(
         functools.partial(_threshold_kernel, n_pairs=n_pairs,
                           block_rows=block_rows, capacity=capacity,
                           threshold=float(threshold), metric=metric),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((1, capacity), jnp.float32),
-                   jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+        out_shape=[out_struct((1, capacity), jnp.float32, *ins),
+                   out_struct((1, capacity), jnp.int32, *ins),
+                   out_struct((1, capacity), jnp.int32, *ins),
+                   out_struct((1, 1), jnp.int32, *ins)],
         interpret=interpret,
-    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-      jnp.asarray(meta, jnp.int32), quorum.astype(jnp.float32),
-      quorum.astype(jnp.float32))
+    )(*ins)
     return vals[0], gi[0], gj[0], count[0, 0]

@@ -1,37 +1,38 @@
-"""Pallas TPU kernel: fused pair scoring + per-row running top-k.
+"""Pallas TPU kernel: fused pair scoring + per-row running top-k, tiled.
 
 The k-NN graph engine's batched inner step (core/knn.py, DESIGN.md
 section 12.3) materializes an [n_pairs, block, block] score tensor and
-sorts every block row's candidate list.  This kernel fuses the whole
-step, one grid step per scheduled slot pair:
+sorts every block row's candidate list.  This kernel fuses the step and
+tiles it, so the block size is bounded by HBM and not by VMEM:
 
-  * slot gather — the scalar-prefetched pair slot ids index the quorum
-    operand directly in the BlockSpec index maps (the pairwise_batch /
-    pairwise_threshold pattern), so each grid step DMAs only its two
-    [block, d] corpus blocks,
-  * tile scoring — the [block, block] dot (or L2) tile lives only in
-    VMEM; the two tile orientations use the orientation-consistent
-    subtraction order of ref.pairwise_topk so both sides of a pair see
-    bit-identical scores to the jnp oracle,
-  * running top-k — a [k*block, topk] (value, index) accumulator pair in
-    VMEM holds every slot row's running neighbor list; the tile's two
-    candidate planes are merged into the ``lo`` and ``hi`` slot row
-    ranges (dynamic-sliced by the prefetched slot ids) with ``topk``
-    rounds of extract-the-maximum under the (-score, index) total order
-    — bit-identical to the two-key-sort selection of the oracle.
+  * directed items — each scheduled slot pair (lo, hi) offers the hi
+    block's rows as candidates to the lo block's rows and, unless it is
+    a self tile, the lo block's rows to the hi block's rows.  The
+    wrapper groups these directed items by receiving slot into a
+    scalar-prefetched table (:func:`directed_items`),
+  * grid ``(receiver slot s, row tile r, item j, column tile c)`` — each
+    [tr, topk] output tile is visited in one consecutive run, so the
+    running (value, index) lists stay resident in VMEM across (j, c) and
+    are written back once; steps past a slot's item count run no body
+    and repeat the previous block index (no DMA),
+  * tile scoring — one [tr, tc] dot (f32 on the MXU at HIGHEST
+    precision) or L2 tile per step.  L2 uses precomputed squared norms
+    and the orientation-consistent order ``(2 d - |cand|^2) - |row|^2``
+    of ref.pairwise_topk, so both orientations of a pair see the
+    oracle's scores; a non-self pair is therefore scored once per
+    orientation (twice the MXU work of one shared tile),
+  * running top-k — ``topk`` rounds of extract-the-maximum under the
+    (-score, index) total order, bit-identical to the oracle's two-key
+    sort.
 
-Masked tiles (the ownership dedup mask rides in ``meta[:, 0]``) skip
-their whole body with ``pl.when``; self tiles contribute one side with
-the diagonal excluded; candidate columns beyond a block's valid-row
-count become (NEG_INF, IDX_SENTINEL) sentinels.
-
-Layout notes (v5e): ``block`` should be a multiple of 8 sublanes (the
-ops.py wrapper zero-pads rows; padded rows are rejected by the valid-row
-bounds so padding is exact) and ``topk`` ideally of the 128-lane tile;
-the extract-max merge is O(block * topk * (topk + block)) VPU work per
-side, far below the tile's O(block^2 * d) MXU work for topk << block.
-Interpret mode on CPU mirrors kernels/ops.py conventions and is swept in
-tests/test_kernels.py against ref.pairwise_topk.
+The table's slot structure comes from the schedule's concrete slot ids;
+the per-device part (the ownership dedup mask in ``meta[:, 0]``, block
+ids, valid-row counts) is a second scalar operand the body reads, so a
+masked tile still streams its blocks but computes nothing.  Self tiles
+exclude the diagonal; candidate columns at or beyond a block's valid-row
+count become (NEG_INF, IDX_SENTINEL) sentinels.  The quantized variant
+(kernels/pairwise_batch_q.py) runs the same grid with int8/bf16 operands
+and a dequant epilogue.
 """
 
 from __future__ import annotations
@@ -40,129 +41,250 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ref import IDX_SENTINEL as _IDX_SENTINEL
-from .ref import NEG_INF, QUERY_METRICS
+from .ref import NEG_INF, PRECISION, QUERY_METRICS, out_struct
 
 IDX_SENTINEL = int(_IDX_SENTINEL)
 
-
-def _merge_rows(vacc_ref, iacc_ref, row0, block: int, topk: int,
-                cand_v, cand_i):
-    """Merge [block, c] candidates into acc rows [row0 : row0+block] with
-    topk rounds of extract-max under the (-score, index) order."""
-    cv = jnp.concatenate([vacc_ref[pl.ds(row0, block), :], cand_v], axis=1)
-    ci = jnp.concatenate([iacc_ref[pl.ds(row0, block), :], cand_i], axis=1)
-    out_v, out_i = [], []
-    for _ in range(topk):
-        m = jnp.max(cv, axis=1)                              # [block]
-        tie = cv == m[:, None]
-        sel = jnp.min(jnp.where(tie, ci, IDX_SENTINEL), axis=1)
-        out_v.append(m)
-        out_i.append(sel)
-        hit = tie & (ci == sel[:, None])
-        cv = jnp.where(hit, NEG_INF, cv)
-        ci = jnp.where(hit, IDX_SENTINEL, ci)
-    vacc_ref[pl.ds(row0, block), :] = jnp.stack(out_v, axis=1)
-    iacc_ref[pl.ds(row0, block), :] = jnp.stack(out_i, axis=1)
+# receiver rows x sender columns per grid step; blocks above TILE_COLS
+# rows are zero-padded to a multiple of it (padded rows are invalid)
+TILE_ROWS = 256
+TILE_COLS = 512
+# directed-item table columns: (sender slot, live, item id) for the
+# index maps and (active, is_self, sender block id, sender valid rows)
+# per item for the body
+_TAB = 3
+_META = 4
+# added to the column index of a non-self tile so it never equals a row
+# index: the diagonal test stays one int compare (no i1 select on TPU)
+_NO_DIAG = 1 << 30
 
 
-def _pairwise_topk_kernel(lo_ref, hi_ref, meta_ref, x_lo_ref, x_hi_ref,
-                          ov_ref, oi_ref, vacc_ref, iacc_ref, *,
-                          n_pairs: int, block_rows: int, topk: int,
-                          metric: str):
-    p = pl.program_id(0)
+def tile_plan(block: int, rows: int = TILE_ROWS, cols: int = TILE_COLS):
+    """(tr, tc, padded block) for a block of ``block`` rows: one tile
+    of the 8-sublane-padded block when it fits ``cols``, else
+    (rows, cols) tiles over a block padded to a multiple of ``cols``."""
+    b8 = -(-block // 8) * 8
+    if b8 <= cols:
+        return b8, b8, b8
+    return rows, cols, -(-block // cols) * cols
 
-    @pl.when(p == 0)
+
+def pad_rows(x, rows: int, axis: int = 1):
+    """Zero-pad ``axis`` of ``x`` up to ``rows`` (no copy when equal)."""
+    extra = rows - x.shape[axis]
+    if extra == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(x, widths)
+
+
+def merge_topk_rows(av, ai, cv, ci, topk: int):
+    """Merge candidates ``(cv, ci)`` [R, C] into running lists ``(av,
+    ai)`` [R, topk] by ``topk`` rounds of extract-the-maximum under the
+    (-score, index) order.  Never concatenates along lanes and writes
+    column t with a lane-iota select, so it lowers on TPU for any C and
+    topk."""
+    col = lax.broadcasted_iota(jnp.int32, av.shape, 1)
+    out_v = jnp.full(av.shape, NEG_INF, jnp.float32)
+    out_i = jnp.full(av.shape, IDX_SENTINEL, jnp.int32)
+    for t in range(topk):
+        m = jnp.maximum(jnp.max(av, axis=1, keepdims=True),
+                        jnp.max(cv, axis=1, keepdims=True))       # [R, 1]
+        tie_a = av == m
+        tie_c = cv == m
+        sel = jnp.minimum(
+            jnp.min(jnp.where(tie_a, ai, IDX_SENTINEL), axis=1, keepdims=True),
+            jnp.min(jnp.where(tie_c, ci, IDX_SENTINEL), axis=1, keepdims=True))
+        out_v = jnp.where(col == t, m, out_v)
+        out_i = jnp.where(col == t, sel, out_i)
+        hit_a = tie_a & (ai == sel)
+        hit_c = tie_c & (ci == sel)
+        av = jnp.where(hit_a, NEG_INF, av)
+        ai = jnp.where(hit_a, IDX_SENTINEL, ai)
+        cv = jnp.where(hit_c, NEG_INF, cv)
+        ci = jnp.where(hit_c, IDX_SENTINEL, ci)
+    return out_v, out_i
+
+
+def directed_items(lo, hi, k: int):
+    """Group every pair's orientations by receiving slot, on the host.
+
+    ``lo``/``hi`` are the concrete slot ids of the schedule.  Returns
+    ``(table, J)``: ``table`` is a flat int32 [k * J * 3] array whose row
+    ``s * J + j`` names receiver slot s's j-th directed item ``(sender
+    slot, live, item id)``, J is the largest item count of any slot, and
+    rows past a slot's count are not live and repeat its last sender (no
+    DMA).  Item i < n_pairs is pair i's lo-receives orientation, item
+    n_pairs + i its hi-receives one, which a self pair does not have."""
+    lo = np.asarray(lo, np.int32)
+    hi = np.asarray(hi, np.int32)
+    recv = np.concatenate([lo, hi])
+    send = np.concatenate([hi, lo])
+    live = np.concatenate([np.ones(lo.shape, bool), lo != hi])
+    per_slot = [np.nonzero(live & (recv == s))[0] for s in range(k)]
+    J = max(1, max(len(items) for items in per_slot))
+    table = np.zeros((k, J, _TAB), np.int32)
+    for s, items in enumerate(per_slot):
+        for j in range(J):
+            if j < len(items):
+                table[s, j] = (send[items[j]], 1, items[j])
+            elif len(items):
+                table[s, j] = (send[items[-1]], 0, items[-1])
+            else:
+                table[s, j] = (s, 0, 0)
+    return table.reshape(-1), J
+
+
+def item_meta(meta):
+    """Per directed item ``(active, is_self, sender block id, sender
+    valid rows)``, flat int32 [2 * n_pairs * 4] in the item order of
+    :func:`directed_items` — the part of the table that varies per
+    device (the dedup mask and the global block ids), read in the body."""
+    meta = jnp.asarray(meta, jnp.int32)
+    act, slf = meta[:, 0], meta[:, 1]
+    items = jnp.stack([
+        jnp.concatenate([act, act * (1 - slf)]),
+        jnp.concatenate([slf, jnp.zeros_like(slf)]),
+        jnp.concatenate([meta[:, 3], meta[:, 2]]),
+        jnp.concatenate([meta[:, 5], meta[:, 4]])], axis=-1)
+    return items.reshape(-1)
+
+
+def _directed_topk_kernel(tab_ref, it_ref, xr_ref, xs_ref, nr_ref, ns_ref,
+                          *rest, J: int, tr: int, tc: int, topk: int,
+                          block_rows: int, metric: str, quant: bool):
+    if quant:
+        sd_ref, ov_ref, oi_ref = rest
+    else:
+        ov_ref, oi_ref = rest
+    s, r, j, c = (pl.program_id(a) for a in range(4))
+    base = (s * J + j) * _TAB
+    m = tab_ref[base + 2] * _META
+
+    @pl.when((j == 0) & (c == 0))
     def _init():
-        vacc_ref[...] = jnp.full_like(vacc_ref, NEG_INF)
-        iacc_ref[...] = jnp.full_like(iacc_ref, IDX_SENTINEL)
+        ov_ref[...] = jnp.full(ov_ref.shape, NEG_INF, jnp.float32)
+        oi_ref[...] = jnp.full(oi_ref.shape, IDX_SENTINEL, jnp.int32)
 
-    @pl.when(meta_ref[p, 0] == 1)
+    @pl.when((tab_ref[base + 1] == 1) & (it_ref[m] == 1))
     def _tile():
-        bi = x_lo_ref[0]                                  # [block, d]
-        bj = x_hi_ref[0]
-        blk = bi.shape[0]
-        dots = jnp.dot(bi, bj.T, preferred_element_type=jnp.float32)
-        if metric == "l2":  # orientation-consistent order: oracle parity
-            bin2 = jnp.sum(bi * bi, axis=-1)
-            bjn2 = jnp.sum(bj * bj, axis=-1)
-            t_lo = (2.0 * dots - bjn2[None, :]) - bin2[:, None]
-            t_hi = (2.0 * dots - bin2[:, None]) - bjn2[None, :]
+        xr = xr_ref[...].astype(jnp.float32)                  # [tr, d]
+        xs = xs_ref[...].astype(jnp.float32)                  # [tc, d]
+        dots = lax.dot_general(xr, xs, (((1,), (1,)), ((), ())),
+                               precision=PRECISION,
+                               preferred_element_type=jnp.float32)
+        if quant:  # dequant epilogue, scale order of ref.pairwise_topk_q
+            dots = dots * (sd_ref[s, 0] * sd_ref[tab_ref[base], 0])
+        if metric == "l2":
+            t = (2.0 * dots - ns_ref[...]) - nr_ref[...]
         else:
-            t_lo = t_hi = dots
-        is_self = meta_ref[p, 1]
-        r = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-        s = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-        # lo side: rows of bi receive bj's valid rows as candidates
-        keep = (s < meta_ref[p, 5]) & jnp.where(is_self == 1, r != s, True)
-        cand_v = jnp.where(keep, t_lo, NEG_INF)
-        cand_i = jnp.where(keep, meta_ref[p, 3] * block_rows + s,
-                           IDX_SENTINEL)
-        _merge_rows(vacc_ref, iacc_ref, lo_ref[p] * blk, blk, topk,
-                    cand_v, cand_i)
+            t = dots
+        rows = r * tr + lax.broadcasted_iota(jnp.int32, (tr, tc), 0)
+        cols = c * tc + lax.broadcasted_iota(jnp.int32, (tr, tc), 1)
+        off = jnp.where(it_ref[m + 1] == 1, 0, _NO_DIAG)
+        keep = (cols < it_ref[m + 3]) & (rows != cols + off)
+        cv = jnp.where(keep, t, NEG_INF)
+        ci = jnp.where(keep, it_ref[m + 2] * block_rows + cols,
+                       IDX_SENTINEL)
+        ov, oi = merge_topk_rows(ov_ref[...], oi_ref[...], cv, ci, topk)
+        ov_ref[...] = ov
+        oi_ref[...] = oi
 
-        # hi side (transposed orientation; self tiles contribute once)
-        @pl.when(is_self == 0)
-        def _hi_side():
-            keep_t = (r < meta_ref[p, 4]).T
-            cv_t = jnp.where(keep_t, t_hi.T, NEG_INF)
-            ci_t = jnp.where(keep_t,
-                             (meta_ref[p, 2] * block_rows + r).T,
-                             IDX_SENTINEL)
-            _merge_rows(vacc_ref, iacc_ref, hi_ref[p] * blk, blk, topk,
-                        cv_t, ci_t)
 
-    @pl.when(p == n_pairs - 1)
-    def _done():
-        ov_ref[...] = vacc_ref[...]
-        oi_ref[...] = iacc_ref[...]
+def directed_topk_call(x, sq, lo, hi, meta, *, topk: int, block_rows: int,
+                       metric: str, sd=None, interpret: bool = False):
+    """Run the tiled directed top-k kernel.
+
+    x: [k, block, d] blocks (f32, or int8/bf16 with ``sd`` [k, 2] f32
+    per-block (scale, delta)); sq: [k, block] f32 squared row norms (read
+    for l2 only); lo/hi/meta as in ref.pairwise_topk.  Returns per-slot
+    running lists ``(vals f32 [k, block, topk], idx i32 [k, block,
+    topk])``."""
+    if metric not in QUERY_METRICS:
+        raise ValueError(f"metric must be one of {QUERY_METRICS}, "
+                         f"got {metric!r}")
+    k, block, d = x.shape
+    n_pairs = lo.shape[0]
+    assert hi.shape == (n_pairs,) and meta.shape == (n_pairs, 6), \
+        (hi.shape, meta.shape)
+    assert sq.shape == (k, block), (sq.shape, x.shape)
+    assert block >= block_rows, (block, block_rows)
+    tr, tc, bp = tile_plan(block)
+    table, J = directed_items(lo, hi, k)
+    table = jnp.asarray(table)
+    items = item_meta(meta)
+    xp = pad_rows(x, bp)
+    sqp = pad_rows(jnp.asarray(sq, jnp.float32), bp)
+    ntc = bp // tc
+
+    def send_index(s, j, c, tab):
+        row = (s * J + j) * _TAB
+        live = tab[row + 1]
+        # a dead step repeats the last live block index: no DMA
+        return tab[row], live * c + (1 - live) * (ntc - 1)
+
+    def xs_map(s, r, j, c, tab, it):
+        slot, col = send_index(s, j, c, tab)
+        return slot, col, 0
+
+    def ns_map(s, r, j, c, tab, it):
+        slot, col = send_index(s, j, c, tab)
+        return slot, 0, col
+
+    recv_map = lambda s, r, j, c, tab, it: (s, r, 0)
+    in_specs = [
+        pl.BlockSpec((None, tr, d), recv_map),
+        pl.BlockSpec((None, tc, d), xs_map),
+        pl.BlockSpec((None, tr, 1), recv_map),
+        pl.BlockSpec((None, 1, tc), ns_map),
+    ]
+    operands = [xp, xp, sqp[:, :, None], sqp[:, None, :]]
+    if sd is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(jnp.asarray(sd, jnp.float32))
+    out_spec = pl.BlockSpec((None, tr, topk), recv_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(k, bp // tr, J, ntc),
+        in_specs=in_specs,
+        out_specs=[out_spec, out_spec],
+    )
+    ins = (table, items, *operands)
+    vals, idx = pl.pallas_call(
+        functools.partial(_directed_topk_kernel, J=J, tr=tr, tc=tc,
+                          topk=topk, block_rows=block_rows, metric=metric,
+                          quant=sd is not None),
+        grid_spec=grid_spec,
+        out_shape=[out_struct((k, bp, topk), jnp.float32, *ins),
+                   out_struct((k, bp, topk), jnp.int32, *ins)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary")),
+        interpret=interpret,
+    )(*ins)
+    return vals[:, :block], idx[:, :block]
 
 
 def pairwise_topk_pallas(quorum: jax.Array, lo: jax.Array, hi: jax.Array,
                          meta: jax.Array, *, topk: int, block_rows: int,
                          metric: str = "dot", interpret: bool = False):
-    """quorum: [k, block, d] corpus blocks; lo/hi: [n_pairs] int32 slot
-    ids; meta: [n_pairs, 6] int32 ``(active, is_self, ga, gb, nv_lo,
-    nv_hi)`` (see ref.pairwise_topk, the bit-parity oracle).
+    """quorum: [k, block, d] corpus blocks; lo/hi: [n_pairs] concrete
+    int32 slot ids; meta: [n_pairs, 6] int32 ``(active, is_self, ga, gb,
+    nv_lo, nv_hi)`` (see ref.pairwise_topk, the bit-parity oracle).
     ``block_rows`` is the unpadded global block stride for row-id math
-    (``block`` may be sublane-padded above it).  Returns the per-slot
-    running top-k after all tiles: ``(vals f32 [k, block, topk],
-    idx i32 [k, block, topk])``.
+    (``block`` may be padded above it).  Returns the per-slot running
+    top-k after all tiles: ``(vals f32 [k, block, topk], idx i32 [k,
+    block, topk])``.
     """
-    if metric not in QUERY_METRICS:
-        raise ValueError(f"metric must be one of {QUERY_METRICS}, "
-                         f"got {metric!r}")
-    k, block, d = quorum.shape
-    n_pairs = lo.shape[0]
-    assert hi.shape == (n_pairs,) and meta.shape == (n_pairs, 6), \
-        (hi.shape, meta.shape)
-    assert block >= block_rows, (block, block_rows)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # lo, hi, meta drive the tiles
-        grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (lo[p], 0, 0)),
-            pl.BlockSpec((1, block, d), lambda p, lo, hi, meta: (hi[p], 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((k * block, topk), lambda p, lo, hi, meta: (0, 0)),
-            pl.BlockSpec((k * block, topk), lambda p, lo, hi, meta: (0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((k * block, topk), jnp.float32),
-                        pltpu.VMEM((k * block, topk), jnp.int32)],
-    )
-    vals, idx = pl.pallas_call(
-        functools.partial(_pairwise_topk_kernel, n_pairs=n_pairs,
-                          block_rows=block_rows, topk=topk, metric=metric),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((k * block, topk), jnp.float32),
-                   jax.ShapeDtypeStruct((k * block, topk), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-      jnp.asarray(meta, jnp.int32), quorum.astype(jnp.float32),
-      quorum.astype(jnp.float32))
-    return vals.reshape(k, block, topk), idx.reshape(k, block, topk)
+    quorum = quorum.astype(jnp.float32)
+    sq = jnp.sum(quorum * quorum, axis=-1)
+    return directed_topk_call(quorum, sq, lo, hi, meta, topk=topk,
+                              block_rows=block_rows, metric=metric,
+                              interpret=interpret)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import out_struct
+
 EPS = 1e-12
 
 DEFAULT_BM = 128
@@ -87,7 +89,7 @@ def pcit_filter_pallas(r_xy, rows_x, rows_y, gx, gy, *,
             pl.BlockSpec((bn,), lambda i, j, z: (j,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, z: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
+        out_shape=out_struct((M, N), jnp.int32, r_xy, rows_x, rows_y, gx, gy),
         interpret=interpret,
     )(r_xy, rows_x, rows_y, gx, gy)
     keep = explained == 0

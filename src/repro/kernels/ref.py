@@ -15,12 +15,41 @@ NEG_INF = -1e30
 # them using this exact value
 IDX_SENTINEL = np.int32(np.iinfo(np.int32).max)
 QUERY_METRICS = ("dot", "l2")
+# Every f32 scoring dot states its precision.  At DEFAULT precision the
+# TPU runs an f32 matmul as one bf16 pass, which reorders near-tied
+# candidates and silently breaks the engine's index-exact contract; the
+# CPU backend computes f32 either way.
+PRECISION = jax.lax.Precision.HIGHEST
 # relative float32-accumulation slack folded into the certified
 # quantization error bound (core/quant.py; DESIGN.md section 17) — a few
 # hundred ulps, orders of magnitude above what a <=2^13-term f32 dot can
 # actually accumulate at the repo's block sizes, and still orders of
 # magnitude below any real quantization error
 FP_REL = 1e-6
+
+
+def vma_of(*xs) -> frozenset:
+    """The mesh axes any of ``xs`` varies over inside ``shard_map`` (empty
+    outside one) — what a ``pallas_call`` output or a scan carry built
+    from constants must be typed with under ``check_vma``."""
+    out = frozenset()
+    for x in xs:
+        out |= jax.typeof(x).vma
+    return out
+
+
+def out_struct(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
+    """A kernel output shape, varying over the axes its inputs vary over."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(*inputs))
+
+
+def varying_like(x, *refs):
+    """Type the constant ``x`` as varying over the axes ``refs`` vary over
+    (a scan's initial carry must match its body's output type)."""
+    vma = tuple(sorted(vma_of(*jax.tree.leaves(refs))))
+    if not vma:
+        return x
+    return jax.tree.map(lambda a: jax.lax.pcast(a, vma, to="varying"), x)
 
 
 def quant_eps_tile(delta_lo, delta_hi, l1_lo, l1_hi, *, dim: int,
@@ -55,7 +84,7 @@ def quant_eps_tile(delta_lo, delta_hi, l1_lo, l1_hi, *, dim: int,
 
 def pairwise_corr(xs_i: jax.Array, xs_j: jax.Array) -> jax.Array:
     """Correlation tile of standardized blocks: [bm, G] x [bn, G] -> [bm, bn]."""
-    return xs_i @ xs_j.T
+    return jnp.dot(xs_i, xs_j.T, precision=PRECISION)
 
 
 def pcit_filter(r_xy, rows_x, rows_y, gx, gy) -> jax.Array:
@@ -124,7 +153,7 @@ def query_topk(stack, queries, mask, gidx, *, topk: int,
     Q = queries.shape[0]
     stack = stack.astype(jnp.float32)
     queries = queries.astype(jnp.float32)
-    s = jnp.einsum("qd,sbd->qsb", queries, stack)
+    s = jnp.einsum("qd,sbd->qsb", queries, stack, precision=PRECISION)
     if metric == "l2":
         s = (2.0 * s - jnp.sum(stack * stack, axis=-1)[None]
              - jnp.sum(queries * queries, axis=-1)[:, None, None])
@@ -165,7 +194,7 @@ def pairwise_threshold(quorum, lo, hi, meta, *, threshold: float,
     meta = jnp.asarray(meta, jnp.int32)
     lhs = jnp.take(quorum, lo, axis=0)          # [n_pairs, block, d]
     rhs = jnp.take(quorum, hi, axis=0)
-    dots = jnp.einsum("pbd,pcd->pbc", lhs, rhs)
+    dots = jnp.einsum("pbd,pcd->pbc", lhs, rhs, precision=PRECISION)
     if metric == "l2":
         scores = (2.0 * dots
                   - jnp.sum(rhs * rhs, axis=-1)[:, None, :]
@@ -233,7 +262,8 @@ def pairwise_threshold_q(q, scale, delta, l1, sq, lo, hi, meta, *,
     rhs = jnp.take(qf, hi, axis=0)
     s_lo = jnp.take(scale, lo)                  # [n_pairs]
     s_hi = jnp.take(scale, hi)
-    dots = jnp.einsum("pbd,pcd->pbc", lhs, rhs) * (s_lo * s_hi)[:, None, None]
+    dots = (jnp.einsum("pbd,pcd->pbc", lhs, rhs, precision=PRECISION)
+            * (s_lo * s_hi)[:, None, None])
     if metric == "l2":
         scores = (2.0 * dots
                   - jnp.take(sq, hi, axis=0)[:, None, :]) \
@@ -314,7 +344,8 @@ def pairwise_topk_q(q, scale, sq, lo, hi, meta, *, topk: int,
         active, is_self, ga, gb, nv_lo, nv_hi = (m[c] for c in range(6))
         bi = jnp.take(qf, lo_p, axis=0)
         bj = jnp.take(qf, hi_p, axis=0)
-        dots = (bi @ bj.T) * (scale[lo_p] * scale[hi_p])  # [block, block]
+        dots = (jnp.dot(bi, bj.T, precision=PRECISION)
+                * (scale[lo_p] * scale[hi_p]))             # [block, block]
         if metric == "l2":
             bin2 = jnp.take(sq, lo_p, axis=0)
             bjn2 = jnp.take(sq, hi_p, axis=0)
@@ -343,6 +374,7 @@ def pairwise_topk_q(q, scale, sq, lo, hi, meta, *, topk: int,
 
     init = (jnp.full((k, block, topk), NEG_INF, jnp.float32),
             jnp.full((k, block, topk), sent, jnp.int32))
+    init = varying_like(init, qf, scale, sq, lo, hi, meta)
     (vals, idx), _ = jax.lax.scan(body, init, (lo, hi, meta))
     return vals, idx
 
@@ -390,7 +422,7 @@ def pairwise_topk(quorum, lo, hi, meta, *, topk: int, block_rows: int,
         active, is_self, ga, gb, nv_lo, nv_hi = (m[c] for c in range(6))
         bi = jnp.take(quorum, lo_p, axis=0)
         bj = jnp.take(quorum, hi_p, axis=0)
-        dots = bi @ bj.T                                  # [block, block]
+        dots = jnp.dot(bi, bj.T, precision=PRECISION)     # [block, block]
         if metric == "l2":
             bin2 = jnp.sum(bi * bi, axis=-1)
             bjn2 = jnp.sum(bj * bj, axis=-1)
@@ -421,6 +453,7 @@ def pairwise_topk(quorum, lo, hi, meta, *, topk: int, block_rows: int,
 
     init = (jnp.full((k, block, topk), NEG_INF, jnp.float32),
             jnp.full((k, block, topk), sent, jnp.int32))
+    init = varying_like(init, quorum, lo, hi, meta)
     (vals, idx), _ = jax.lax.scan(body, init, (lo, hi, meta))
     return vals, idx
 

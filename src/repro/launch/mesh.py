@@ -34,6 +34,13 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
+def shard_rows(x, mesh: Mesh, axis_name: str) -> jax.Array:
+    """Stage a host array onto ``mesh`` with its leading axis split over
+    ``axis_name``: each device receives only its own rows, instead of the
+    whole array landing on one device before shard_map reshards it."""
+    return jax.device_put(x, NamedSharding(mesh, PS(axis_name)))
+
+
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:
     """Axes carrying the batch: ("pod", "data") on multi-pod meshes."""
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))

@@ -126,7 +126,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     P = len(jax.devices())
-    mesh = jax.make_mesh((P,), ("q",))
+    from .mesh import make_mesh
+    mesh = make_mesh((P,), ("q",))
     rng = np.random.default_rng(args.seed)
     corpus = rng.normal(size=(args.n, args.d)).astype(np.float32)
     queries = rng.normal(size=(args.requests, args.d)).astype(np.float32)

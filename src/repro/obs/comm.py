@@ -195,7 +195,8 @@ def verify_dense_comm(P: Optional[int] = None,
     Pn = P or len(devs)
     if len(devs) < Pn:
         raise RuntimeError(f"need {Pn} devices, have {len(devs)}")
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(Pn * block, dim)) * 10).astype(dtype)
     block_bytes = block_bytes_of(block, dim, dtype)
@@ -267,7 +268,8 @@ def verify_quant_comm(P: Optional[int] = None,
     Pn = P or len(devs)
     if len(devs) < Pn:
         raise RuntimeError(f"need {Pn} devices, have {len(devs)}")
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     rng = np.random.default_rng(0)
     x = rng.normal(size=(Pn * block, dim)).astype(np.float32)
     qc = quantize_corpus(x, Pn, block, qmode)

@@ -714,7 +714,8 @@ def main(nblocks: Optional[int] = None) -> None:
     devs = jax.devices()
     P = nblocks or len(devs)
     assert len(devs) >= P, f"need {P} devices, have {len(devs)}"
-    mesh = jax.make_mesh((P,), ("q",), devices=devs[:P])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((P,), ("q",), devices=devs[:P])
     block, d = 16, 24
     rng = np.random.default_rng(0)
     N = P * block - block // 2          # ragged tail: validity masking on

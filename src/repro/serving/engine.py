@@ -56,6 +56,7 @@ from ..core.scheduler import PairSchedule
 from ..core.sparse import default_capacity
 from ..core.sweep import SweepEmitter, merge_topk, slot_items, topk_by_score
 from ..kernels.ref import IDX_SENTINEL, NEG_INF, QUERY_METRICS as METRICS
+from ..kernels.ref import PRECISION, varying_like
 from ..obs import trace as obs_trace
 from .cover import build_cover
 from .stream import ServingState, build_state, replace_block
@@ -97,7 +98,7 @@ def _scores(queries: jax.Array, blk: jax.Array, metric: str) -> jax.Array:
     and the fused kernel use the identical formula so float rounding, and
     therefore ranking, agree everywhere.
     """
-    dot = queries @ blk.T
+    dot = jnp.dot(queries, blk.T, precision=PRECISION)
     if metric == "dot":
         return dot
     if metric == "l2":
@@ -180,7 +181,8 @@ class QueryTopKEmitter(SweepEmitter):
         if self.batch_fn is not None:
             return self.batch_fn(quorum, self.queries,
                                  self.mask.astype(jnp.float32), self.gidx)
-        s = jnp.einsum("qd,sbd->qsb", self.queries, quorum)
+        s = jnp.einsum("qd,sbd->qsb", self.queries, quorum,
+                       precision=PRECISION)
         if self.metric == "l2":
             s = (2.0 * s - jnp.sum(quorum * quorum, axis=-1)[None]
                  - jnp.sum(self.queries * self.queries, axis=-1)[:, None, None])
@@ -195,10 +197,13 @@ class QueryTopKEmitter(SweepEmitter):
                              flat_idx.reshape(Q, k * block), self.topk)
 
     def scan_init(self):
-        """Sentinel-filled [Q, topk] running lists."""
+        """Sentinel-filled [Q, topk] running lists, varying like the
+        per-device mask the body folds in."""
         Q = self.queries.shape[0]
-        return (jnp.full((Q, self.topk), NEG_INF, self.queries.dtype),
-                jnp.full((Q, self.topk), IDX_SENTINEL, jnp.int32))
+        return varying_like(
+            (jnp.full((Q, self.topk), NEG_INF, jnp.float32),
+             jnp.full((Q, self.topk), IDX_SENTINEL, jnp.int32)),
+            self.queries, self.mask, self.gidx)
 
     def scan_items(self):
         """(slot, mask row, global-id row) per resident slot."""
@@ -358,7 +363,8 @@ class QueryThresholdEmitter(SweepEmitter):
         k, block = quorum.shape[0], quorum.shape[1]
         Q = self.queries.shape[0]
         vbuf, ibuf, cnt = self._init_bufs()
-        s = jnp.einsum("qd,sbd->qsb", self.queries, quorum)
+        s = jnp.einsum("qd,sbd->qsb", self.queries, quorum,
+                       precision=PRECISION)
         if self.metric == "l2":
             s = (2.0 * s - jnp.sum(quorum * quorum, axis=-1)[None]
                  - jnp.sum(self.queries * self.queries, axis=-1)[:, None, None])
@@ -551,13 +557,13 @@ def query_fn(mesh, axis_name: str, topk: int, mode: str, metric: str,
     sched = placement.schedule()
     plan = build_cover(P, placement)
     mask_table = jnp.asarray(plan.mask_table())          # [P, k]
+    from ..kernels import ops as kops
     batch_fn = None
     if use_kernel:
         if mode not in ("batched", "auto"):
             raise ValueError(
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched local step")
-        from ..kernels import ops as kops
         batch_fn = functools.partial(kops.query_topk, topk=topk,
                                      metric=metric)
 
@@ -571,7 +577,8 @@ def query_fn(mesh, axis_name: str, topk: int, mode: str, metric: str,
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(PS(), spec, spec, spec),
-        out_specs=(spec, spec)))
+        out_specs=(spec, spec),
+        check_vma=kops.shard_map_check_vma(use_kernel)))
 
     def run(queries, state: ServingState):
         vals, idx = fn(queries, state.stack, state.stack_valid, mask_table)

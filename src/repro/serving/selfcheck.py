@@ -147,7 +147,8 @@ def main(nblocks: int | None = None,
     assert len(devs) >= Pn, f"need {Pn} devices, have {len(devs)}"
     plc = (placement_from_env(Pn) if placement is None
            else resolve_placement(placement, Pn))
-    mesh = jax.make_mesh((Pn,), ("q",), devices=devs[:Pn])
+    from ..launch.mesh import make_mesh
+    mesh = make_mesh((Pn,), ("q",), devices=devs[:Pn])
     block, d, Q, topk = 16, 24, 12, 8
     rng = np.random.default_rng(0)
     # leave one block's worth of rows empty: exercises validity masking

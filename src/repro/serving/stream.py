@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as PS
 
 from ..core.allpairs import quorum_gather
 from ..core.placement import Placement, placement_from_env, resolve_placement
+from ..launch.mesh import shard_rows
 
 __all__ = ["ServingState", "build_state", "update_fn", "replace_block",
            "register_dirty_listener", "unregister_dirty_listener"]
@@ -151,9 +152,9 @@ def build_state(corpus: np.ndarray, mesh, axis_name: str = "q",
     N, d = corpus.shape
     block = max(block or 1, 1, -(-N // P))
     pad = P * block - N
-    shard = jnp.asarray(np.pad(np.asarray(corpus, np.float32),
-                               ((0, pad), (0, 0))))
-    valid = jnp.arange(P * block) < N
+    shard = shard_rows(np.pad(np.asarray(corpus, np.float32),
+                              ((0, pad), (0, 0))), mesh, axis_name)
+    valid = shard_rows(np.arange(P * block) < N, mesh, axis_name)
     stack, stack_valid = _build_fn(mesh, axis_name, P, plc)(shard, valid)
     return ServingState(shard=shard, valid=valid, stack=stack,
                         stack_valid=stack_valid)

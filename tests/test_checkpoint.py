@@ -176,7 +176,8 @@ tmp = {str(tmp_path)!r}
 P_old, P_new = 4, 6
 N, d = 48, 8                      # divisible by both P values
 devs = jax.devices()
-mesh_old = jax.make_mesh((P_old,), ("q",), devices=devs[:P_old])
+from repro.launch.mesh import make_mesh
+mesh_old = make_mesh((P_old,), ("q",), devices=devs[:P_old])
 rng = np.random.default_rng(1)
 corpus = rng.normal(size=(N, d)).astype(np.float32)
 x = jax.device_put(jnp.asarray(corpus), NamedSharding(mesh_old, PS("q")))
@@ -187,7 +188,8 @@ assert plan.P_new == P_new and plan.schedule.P == P_new
 # a resize reuses nothing: every device fetches its whole new residency
 assert plan.fetches == {{i: list(q) for i, q in enumerate(plan.new_quorums)}}
 
-mesh_new = jax.make_mesh((P_new,), ("q",), devices=devs[:P_new])
+from repro.launch.mesh import make_mesh
+mesh_new = make_mesh((P_new,), ("q",), devices=devs[:P_new])
 sh_new = NamedSharding(mesh_new, PS("q"))
 restored, step = load_checkpoint(tmp, {{"corpus": jnp.zeros((N, d))}},
                                  shardings={{"corpus": sh_new}})

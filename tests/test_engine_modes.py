@@ -106,7 +106,8 @@ P, block = 6, 8
 sched = build_schedule(P)
 rng = np.random.default_rng(0)
 x = rng.normal(size=(P * block, 3)).astype(np.float32)
-mesh = jax.make_mesh((P,), ("q",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((P,), ("q",))
 for mode in ["scan", "batched", "overlap"]:
     def f(xb):
         return quorum_allpairs(pairwise_force, xb, axis_name="q",

@@ -1,17 +1,10 @@
-"""Direct coverage for the kernels/ops.py dispatch layers.
+"""Direct coverage for the kernels/ops.py dispatch layer.
 
-The engine ``batch_fn`` hooks (pairwise_batch_forces, query_topk,
-pairwise_threshold) route through two fallback paths that the engine
-sweeps only exercise indirectly:
-
-  * **interpret-mode dispatch** — ``_interpret()`` selects interpret mode
-    off-TPU and compiled mode on TPU; the flag must actually reach the
-    Pallas launch.
-  * **kernel-absent fallback** — when the Pallas machinery itself raises
-    ImportError / NotImplementedError (a jax build without a usable
-    lowering), ``_call_with_fallback`` degrades to the ref.py oracle with
-    a RuntimeWarning instead of failing; other exception types (real
-    kernel bugs) must propagate.
+The engine ``batch_fn`` hooks route every Pallas launch through
+``_interpret()``: interpret mode on the CPU backend, compiled everywhere
+else.  There is no fallback to the ref.py oracles — a kernel
+that cannot lower must raise, so a run that asked for the kernel never
+silently measures the jnp path instead.
 
 Shapes here are deliberately distinct from tests/test_kernels.py so every
 call traces fresh — the jitted entry points would otherwise replay a
@@ -26,51 +19,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.kernels import pairwise_batch_q as pbq_mod
 from repro.kernels import pairwise_threshold as pt_mod
+from repro.kernels import pairwise_topk as ptk_mod
 from repro.kernels import query_score as qs_mod
 
 RNG = np.random.default_rng(7)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_fallback_warnings():
-    """The kernel-absent fallback warns once per hook per process
-    (ops._warned_fallback); clear the keyset so every test here sees its
-    own first warning regardless of execution order."""
-    ops._warned_fallback.clear()
-    yield
-    ops._warned_fallback.clear()
-
-
-def test_fallback_warns_once_per_hook(monkeypatch):
-    """The RuntimeWarning fires on the first kernel-absent call of a
-    hook and stays silent on repeats (a hot engine loop retraces the
-    hook constantly — per-call warnings flood the log), while a
-    *different* hook still gets its own first warning."""
-    monkeypatch.setattr(
-        ops, "pairwise_batch_pallas",
-        lambda *a, **k: (_ for _ in ()).throw(ImportError("no pallas")))
-    quorum, lo, hi, wi, wj = _forces_args(block=17)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # second call: no warning
-        out = ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-    want = ref.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    # an unrelated hook is keyed separately: its first failure warns
-    monkeypatch.setattr(
-        qs_mod, "query_topk_pallas",
-        lambda *a, **k: (_ for _ in ()).throw(ImportError("no pallas")))
-    k, block, d, Q, topk = 3, 12, 6, 5, 4
-    stack = jnp.asarray(RNG.normal(size=(k, block, d)), jnp.float32)
-    queries = jnp.asarray(RNG.normal(size=(Q, d)), jnp.float32)
-    mask = jnp.ones((k, block), jnp.float32)
-    gidx = jnp.asarray(
-        np.arange(k * block, dtype=np.int32).reshape(k, block))
-    with pytest.warns(RuntimeWarning, match="query_topk"):
-        ops.query_topk(stack, queries, mask, gidx, topk=topk)
 
 
 def _forces_args(k=5, block=9, n_pairs=7):
@@ -86,10 +40,13 @@ def _forces_args(k=5, block=9, n_pairs=7):
 
 def test_interpret_dispatch_tracks_backend(monkeypatch):
     """_interpret() is the single source of the interpret/compiled
-    decision: True off-TPU, False on TPU."""
-    assert jax.default_backend() != "tpu"       # the CI/test environment
+    decision: True on CPU, False (compiled) on TPU and on any other
+    backend."""
+    assert jax.default_backend() == "cpu"       # the CI/test environment
     assert ops._interpret() is True
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     assert ops._interpret() is False
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert ops._interpret() is True
@@ -97,82 +54,106 @@ def test_interpret_dispatch_tracks_backend(monkeypatch):
 
 def test_interpret_flag_reaches_pallas_launch(monkeypatch):
     """The hook wrappers pass _interpret()'s verdict into the Pallas
-    call (recorded via a shim that then falls back, so the assertion
-    works on any backend)."""
+    call (recorded by a shim that then runs the real kernel)."""
     seen = {}
+    real = ops.pairwise_batch_pallas
 
     def shim(*args, **kwargs):
         seen["interpret"] = kwargs.get("interpret")
-        raise NotImplementedError("recorded, now force the ref path")
+        return real(*args, **kwargs)
 
     monkeypatch.setattr(ops, "pairwise_batch_pallas", shim)
     quorum, lo, hi, wi, wj = _forces_args(block=11)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        out = ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
+    out = ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
     assert seen["interpret"] is True            # CPU backend -> interpret
     want = ref.pairwise_batch_forces(quorum, lo, hi, wi, wj)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
 
-def test_forces_kernel_absent_falls_back_to_ref(monkeypatch):
-    monkeypatch.setattr(
-        ops, "pairwise_batch_pallas",
-        lambda *a, **k: (_ for _ in ()).throw(ImportError("no pallas")))
-    quorum, lo, hi, wi, wj = _forces_args(block=13)
-    with pytest.warns(RuntimeWarning, match="pairwise_batch_forces"):
-        out = ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-    want = ref.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+def _lowering_error(*args, **kwargs):
+    raise NotImplementedError("no Mosaic lowering for this kernel")
 
 
-def test_query_topk_kernel_absent_falls_back_to_ref(monkeypatch):
-    def absent(*a, **k):
-        raise NotImplementedError("no mosaic lowering")
-
-    monkeypatch.setattr(qs_mod, "query_topk_pallas", absent)
-    k, block, d, Q, topk = 3, 10, 6, 7, 5
-    stack = jnp.asarray(RNG.normal(size=(k, block, d)), jnp.float32)
-    queries = jnp.asarray(RNG.normal(size=(Q, d)), jnp.float32)
-    mask = (RNG.uniform(size=(k, block)) > 0.4).astype(np.float32)
-    gidx = np.arange(k * block, dtype=np.int32).reshape(k, block)
-    with pytest.warns(RuntimeWarning, match="query_topk"):
-        got_v, got_i = ops.query_topk(stack, queries, jnp.asarray(mask),
-                                      jnp.asarray(gidx), topk=topk)
-    # the ref path sees the same padded-Q operand the kernel would have
-    want_v, want_i = ref.query_topk(stack, jnp.pad(queries, ((0, 1), (0, 0))),
-                                    mask, gidx, topk=topk)
-    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i[:Q]))
-    np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v[:Q]),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_pairwise_threshold_kernel_absent_falls_back_to_ref(monkeypatch):
-    monkeypatch.setattr(
-        pt_mod, "pairwise_threshold_pallas",
-        lambda *a, **k: (_ for _ in ()).throw(ImportError("no pallas")))
-    k, block, n_pairs, d = 3, 7, 4, 5
+def _pair_args(block, k=3, n_pairs=4, d=5):
     quorum = jnp.asarray(RNG.normal(size=(k, block, d)), jnp.float32)
     lo = RNG.integers(0, k, n_pairs).astype(np.int32)
     hi = RNG.integers(0, k, n_pairs).astype(np.int32)
-    meta = np.stack([np.ones(n_pairs), (lo == hi),
-                     RNG.integers(0, 4, n_pairs),
-                     RNG.integers(0, 4, n_pairs),
-                     np.full(n_pairs, block),
+    meta = np.stack([np.ones(n_pairs), (lo == hi), np.arange(n_pairs),
+                     n_pairs + np.arange(n_pairs), np.full(n_pairs, block),
                      np.full(n_pairs, block)], 1).astype(np.int32)
-    with pytest.warns(RuntimeWarning, match="pairwise_threshold"):
-        got = ops.pairwise_threshold(quorum, lo, hi, jnp.asarray(meta),
-                                     threshold=0.4, capacity=100,
-                                     block_rows=block)
-    # the wrapper pads rows to 8 sublanes and capacity to 128 lanes
-    qp = jnp.pad(quorum, ((0, 0), (0, 1), (0, 0)))
-    want = ref.pairwise_threshold(qp, lo, hi, meta, threshold=0.4,
-                                  capacity=128, block_rows=block)
-    for g, w in zip(got[:3], want[:3]):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w)[:100],
-                                   rtol=1e-5, atol=1e-5)
-    assert int(got[3]) == int(want[3])
+    return quorum, lo, hi, jnp.asarray(meta)
+
+
+def _quant_args(block):
+    quorum, lo, hi, meta = _pair_args(block)
+    q = jnp.round(quorum * 20).astype(jnp.int8)
+    sd = jnp.tile(jnp.asarray([[0.05, 0.025]], jnp.float32), (q.shape[0], 1))
+    sq = jnp.sum(quorum * quorum, axis=-1)
+    return q, sd, sq, lo, hi, meta
+
+
+def _call_forces():
+    ops.pairwise_batch_forces(*_forces_args(block=19))
+
+
+def _call_query():
+    k, block, d = 3, 14, 6
+    stack = jnp.asarray(RNG.normal(size=(k, block, d)), jnp.float32)
+    queries = jnp.asarray(RNG.normal(size=(5, d)), jnp.float32)
+    ops.query_topk(stack, queries, jnp.ones((k, block), jnp.float32),
+                   jnp.arange(k * block, dtype=jnp.int32).reshape(k, block),
+                   topk=3)
+
+
+def _call_threshold():
+    ops.pairwise_threshold(*_pair_args(21), threshold=0.4, capacity=100,
+                           block_rows=21)
+
+
+def _call_topk():
+    ops.pairwise_topk(*_pair_args(22), topk=3, block_rows=22)
+
+
+def _call_threshold_q():
+    q, sd, sq, lo, hi, meta = _quant_args(23)
+    l1 = jnp.sum(jnp.abs(q.astype(jnp.float32)), axis=-1) * 0.05
+    ops.pairwise_threshold_q(q, sd, l1, sq, lo, hi, meta, threshold=0.4,
+                             capacity=64, block_rows=23)
+
+
+def _call_topk_q():
+    ops.pairwise_topk_q(*_quant_args(25), topk=3, block_rows=25)
+
+
+@pytest.mark.parametrize("target,attr,call", [
+    (ops, "pairwise_batch_pallas", _call_forces),
+    (qs_mod, "query_topk_pallas", _call_query),
+    (pt_mod, "pairwise_threshold_pallas", _call_threshold),
+    (ptk_mod, "pairwise_topk_pallas", _call_topk),
+    (pbq_mod, "pairwise_threshold_q_pallas", _call_threshold_q),
+    (pbq_mod, "pairwise_topk_q_pallas", _call_topk_q),
+], ids=["pairwise_batch_forces", "query_topk", "pairwise_threshold",
+        "pairwise_topk", "pairwise_threshold_q", "pairwise_topk_q"])
+def test_lowering_error_propagates(monkeypatch, target, attr, call):
+    """A kernel that cannot lower raises out of its engine hook: no hook
+    swaps in the jnp oracle (which would hide the device path)."""
+    monkeypatch.setattr(target, attr, _lowering_error)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(NotImplementedError, match="no Mosaic lowering"):
+            call()
+
+
+def test_join_kernels_refuse_to_compile_for_tpu(monkeypatch):
+    """The fused join kernels have no TPU lowering (in-tile cumsum
+    compaction); on a compiling backend their hooks raise with that
+    reason before any launch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="ROADMAP S4"):
+        _call_threshold()
+    with pytest.raises(NotImplementedError, match="ROADMAP S4"):
+        _call_threshold_q()
 
 
 def test_real_kernel_bugs_still_propagate(monkeypatch):
@@ -187,31 +168,3 @@ def test_real_kernel_bugs_still_propagate(monkeypatch):
         warnings.simplefilter("error")          # no fallback warning either
         with pytest.raises(ValueError, match="genuine kernel bug"):
             ops.pairwise_batch_forces(quorum, lo, hi, wi, wj)
-
-
-def test_pairwise_topk_kernel_absent_falls_back_to_ref(monkeypatch):
-    from repro.kernels import pairwise_topk as ptk_mod
-    monkeypatch.setattr(
-        ptk_mod, "pairwise_topk_pallas",
-        lambda *a, **k: (_ for _ in ()).throw(ImportError("no pallas")))
-    k, block, n_pairs, d, topk = 3, 6, 4, 5, 3
-    quorum = jnp.asarray(RNG.normal(size=(k, block, d)), jnp.float32)
-    lo = RNG.integers(0, k, n_pairs).astype(np.int32)
-    hi = RNG.integers(0, k, n_pairs).astype(np.int32)
-    meta = np.stack([np.ones(n_pairs), (lo == hi),
-                     np.arange(n_pairs),
-                     n_pairs + np.arange(n_pairs),
-                     np.full(n_pairs, block),
-                     np.full(n_pairs, block)], 1).astype(np.int32)
-    with pytest.warns(RuntimeWarning, match="pairwise_topk"):
-        got_v, got_i = ops.pairwise_topk(quorum, lo, hi, jnp.asarray(meta),
-                                         topk=topk, block_rows=block)
-    # the wrapper pads rows to 8 sublanes; the ref path sees the padding
-    qp = jnp.pad(quorum, ((0, 0), (0, 2), (0, 0)))
-    want_v, want_i = ref.pairwise_topk(qp, lo, hi, meta, topk=topk,
-                                       block_rows=block)
-    np.testing.assert_array_equal(np.asarray(got_i),
-                                  np.asarray(want_i)[:, :block])
-    np.testing.assert_allclose(np.asarray(got_v),
-                               np.asarray(want_v)[:, :block],
-                               rtol=1e-5, atol=1e-5)

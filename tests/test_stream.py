@@ -33,7 +33,8 @@ def test_append_then_replace_ordering_same_block():
     code = """
 import jax, numpy as np
 from repro.serving.stream import build_state, replace_block
-mesh = jax.make_mesh((4,), ("q",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("q",))
 rng = np.random.default_rng(0)
 corpus = rng.normal(size=(8, 3)).astype(np.float32)
 st = build_state(corpus, mesh, block=4)        # capacity 16; block 3 hosts
@@ -61,7 +62,8 @@ from repro.core.placement import get_placement
 from repro.serving.stream import build_state, replace_block
 P = 5
 plc = get_placement("cyclic", P)
-mesh = jax.make_mesh((P,), ("q",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((P,), ("q",))
 rng = np.random.default_rng(1)
 corpus = rng.normal(size=(P * 2, 3)).astype(np.float32)
 st = build_state(corpus, mesh, placement=plc)
@@ -99,7 +101,8 @@ import jax, numpy as np
 from repro.core.placement import placement_from_env
 from repro.serving.stream import build_state, replace_block
 P = 4
-mesh = jax.make_mesh((P,), ("q",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((P,), ("q",))
 rng = np.random.default_rng(2)
 corpus = rng.normal(size=(6, 3)).astype(np.float32)
 st = build_state(corpus, mesh, block=2)         # block 3 (rows 6,7) empty
@@ -145,7 +148,8 @@ def test_dirty_listener_fires_per_update():
 import jax, numpy as np
 from repro.serving.stream import (build_state, register_dirty_listener,
                                   replace_block, unregister_dirty_listener)
-mesh = jax.make_mesh((4,), ("q",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("q",))
 rng = np.random.default_rng(3)
 corpus = rng.normal(size=(8, 3)).astype(np.float32)
 st = build_state(corpus, mesh)
