@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU: its own directory and the
+program's ``src/`` go on the import path."""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
