@@ -53,6 +53,7 @@ from jax import lax
 from ..kernels.ref import IDX_SENTINEL, NEG_INF, PRECISION
 from ..kernels.ref import QUERY_METRICS as KNN_METRICS
 from ..launch.mesh import make_mesh, shard_rows
+from ..obs import trace as obs_trace
 from . import sweep as sweep_mod
 from .scheduler import PairSchedule
 from .sparse import _pair_meta, _pair_score_matrix
@@ -398,18 +399,31 @@ def knn_graph(corpus, mesh, *, topk: int, axis_name: str = "q",
             corpus, mesh, topk=topk, quant=quant, axis_name=axis_name,
             metric=metric, mode=mode, placement=placement,
             use_kernel=use_kernel)
-    corpus = np.asarray(corpus, np.float32)
-    N, d = corpus.shape
+    N = len(corpus)
     P = mesh.shape[axis_name]
     from .placement import placement_from_env, resolve_placement
     plc = (placement_from_env(P) if placement is None
            else resolve_placement(placement, P))
     block = -(-N // P)
-    x = np.zeros((P * block, d), np.float32)
-    x[:N] = corpus
-    run = _knn_fn(mesh, axis_name, N, block, int(topk), metric, mode,
-                  use_kernel, plc)
-    vals, idx = (np.asarray(a) for a in run(shard_rows(x, mesh, axis_name)))
+    # host spans of one build (DESIGN.md section 14.1): the device idles
+    # in all but knn.wait
+    tr = obs_trace.get_tracer()
+    with tr.span("knn.stage", rows=N, P=P):
+        corpus = np.asarray(corpus, np.float32)
+        x = np.zeros((P * block, corpus.shape[1]), np.float32)
+        x[:N] = corpus
+    with tr.span("knn.upload", rows=N, P=P):
+        # blocks until the rows are on the chips, so that the span holds
+        # the transfer; the program could not start before it anyway
+        xs = jax.block_until_ready(shard_rows(x, mesh, axis_name))
+    with tr.span("knn.dispatch", rows=N, P=P):
+        run = _knn_fn(mesh, axis_name, N, block, int(topk), metric, mode,
+                      use_kernel, plc)
+        out = run(xs)
+    with tr.span("knn.wait", rows=N, P=P):
+        jax.block_until_ready(out)
+    with tr.span("knn.fetch", rows=N, P=P):
+        vals, idx = (np.asarray(a) for a in out)
     return KnnResult(indices=idx[:N], scores=vals[:N], topk=int(topk))
 
 

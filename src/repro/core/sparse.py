@@ -665,10 +665,8 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
 
     escalations = 0
     tr = obs_trace.get_tracer()
-    span = tr.span("sparse.join", N=N, P=P, metric=metric, mode=mode,
-                   threshold=float(threshold), placement=plc.name) if tr \
-        else obs_trace.NOOP.span("")
-    with span:
+    with tr.span("sparse.join", N=N, P=P, metric=metric, mode=mode,
+                 threshold=float(threshold), placement=plc.name):
         while True:
             run = _join_fn(mesh, axis_name, N, block, float(threshold),
                            metric, mode, cap, prefilter, use_kernel, plc)

@@ -221,9 +221,7 @@ def quorum_gather(x, schedule: PairSchedule, axis_name: str,
         nz = sum(1 for a in shifts if a % P != 0)
         tr.count("comm.ppermute.gather_hops", nz)
         tr.count("comm.ppermute.gather_bytes", nz * _tree_nbytes(x))
-    span = tr.span("sweep.gather", P=P, k=len(shifts)) if tr \
-        else obs_trace.NOOP.span("")
-    with span:
+    with jax.named_scope("sweep.gather"):
         blocks = []
         results = []
         for slot, a in enumerate(shifts):
@@ -261,9 +259,7 @@ def quorum_scatter(partials, schedule: PairSchedule, axis_name: str,
     P = schedule.P
     shifts = [int(s) for s in schedule.shifts]
     tr = obs_trace.get_tracer()
-    span = tr.span("sweep.scatter", P=P, k=len(shifts)) if tr \
-        else obs_trace.NOOP.span("")
-    with span:
+    with jax.named_scope("sweep.scatter"):
         acc = None
         for slot, a in enumerate(shifts):
             part = partials[slot]
@@ -504,26 +500,13 @@ def pair_sweep(emitter: SweepEmitter, *, schedule: PairSchedule,
     :func:`select_mode` (each adapter supplies its working-set bytes).
     Returns whatever the emitter's finalize step produces.
     """
-    tr = obs_trace.get_tracer()
-    if not tr:
-        return _pair_sweep_impl(emitter, schedule=schedule,
-                                axis_name=axis_name, mode=mode, x=x,
-                                stack=stack)
-    lo, _hi = emitter.items()
-    with tr.span("sweep.pair_compute", mode=mode, P=schedule.P,
-                 k=schedule.k, n_items=int(len(lo))):
-        tr.count("sweep.pair_tiles", int(len(lo)))
-        return _pair_sweep_impl(emitter, schedule=schedule,
-                                axis_name=axis_name, mode=mode, x=x,
-                                stack=stack)
-
-
-def _pair_sweep_impl(emitter: SweepEmitter, *, schedule: PairSchedule,
-                     axis_name: str, mode: str, x: jax.Array | None = None,
-                     stack: jax.Array | None = None):
-    # the un-instrumented driver body (pair_sweep is the traced wrapper)
     assert (x is None) != (stack is None), "need exactly one of x / stack"
     assert mode in ENGINE_MODES, mode
+    tr = obs_trace.get_tracer()
+    if tr:  # exact at jit-trace time: the schedule is static
+        tr.count("sweep.pair_tiles", int(len(emitter.items()[0])))
+    # device ops of the pair math carry the sweep.pair_compute scope (in
+    # the overlap mode it nests under sweep.gather, which interleaves it)
     if mode == "overlap":
         lo, hi = emitter.items()
         ready = ready_order(lo, hi, schedule.k)
@@ -532,30 +515,33 @@ def _pair_sweep_impl(emitter: SweepEmitter, *, schedule: PairSchedule,
 
         def on_land(slot: int, blk: jax.Array) -> None:
             landed.append(blk)
-            emitter.overlap_slot(state, slot, blk)
-            for idx in ready[slot]:
-                emitter.overlap_emit(state, idx,
-                                     landed[int(lo[idx])],
-                                     landed[int(hi[idx])])
+            with jax.named_scope("sweep.pair_compute"):
+                emitter.overlap_slot(state, slot, blk)
+                for idx in ready[slot]:
+                    emitter.overlap_emit(state, idx,
+                                         landed[int(lo[idx])],
+                                         landed[int(hi[idx])])
 
         if stack is None:
             quorum_gather(x, schedule, axis_name, overlap_fn=on_land)
         else:
             for slot in range(schedule.k):
                 on_land(slot, jax.tree.map(lambda l: l[slot], stack))
-        return emitter.overlap_finalize(state)
+        with jax.named_scope("sweep.pair_compute"):
+            return emitter.overlap_finalize(state)
 
     quorum = stack if stack is not None else quorum_gather(x, schedule,
                                                            axis_name)
-    emitter.prepare(quorum)
-    if mode == "batched":
-        return emitter.batch(quorum)
+    with jax.named_scope("sweep.pair_compute"):
+        emitter.prepare(quorum)
+        if mode == "batched":
+            return emitter.batch(quorum)
 
-    def body(carry, item):
-        return emitter.scan_emit(carry, quorum, item), None
+        def body(carry, item):
+            return emitter.scan_emit(carry, quorum, item), None
 
-    carry, _ = lax.scan(body, emitter.scan_init(), emitter.scan_items())
-    return emitter.scan_finalize(carry)
+        carry, _ = lax.scan(body, emitter.scan_init(), emitter.scan_items())
+        return emitter.scan_finalize(carry)
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ def shard_map_check_vma(use_kernel: bool) -> bool:
     is refused under it, so on CPU a kernel-carrying program runs
     unchecked; on TPU the kernels compile and the typing stays checked
     (tests/test_tpu_compile.py compiles the k-NN sweep that way).
-    Programs without a kernel are always checked."""
+    Programs without a kernel are always checked (DESIGN.md section 6)."""
     return not (use_kernel and _interpret())
 
 

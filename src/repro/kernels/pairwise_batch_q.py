@@ -212,5 +212,6 @@ def pairwise_topk_q_pallas(q: jax.Array, sd: jax.Array, sq: jax.Array,
     k = q.shape[0]
     assert sd.shape == (k, 2), sd.shape
     return directed_topk_call(q, sq, lo, hi, meta, topk=topk,
-                              block_rows=block_rows, metric=metric, sd=sd,
+                              block_rows=block_rows, metric=metric,
+                              name="pairwise_topk_q", sd=sd,
                               interpret=interpret)

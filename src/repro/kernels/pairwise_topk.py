@@ -199,14 +199,17 @@ def _directed_topk_kernel(tab_ref, it_ref, xr_ref, xs_ref, nr_ref, ns_ref,
 
 
 def directed_topk_call(x, sq, lo, hi, meta, *, topk: int, block_rows: int,
-                       metric: str, sd=None, interpret: bool = False):
+                       metric: str, name: str, sd=None,
+                       interpret: bool = False):
     """Run the tiled directed top-k kernel.
 
     x: [k, block, d] blocks (f32, or int8/bf16 with ``sd`` [k, 2] f32
     per-block (scale, delta)); sq: [k, block] f32 squared row norms (read
-    for l2 only); lo/hi/meta as in ref.pairwise_topk.  Returns per-slot
-    running lists ``(vals f32 [k, block, topk], idx i32 [k, block,
-    topk])``."""
+    for l2 only); lo/hi/meta as in ref.pairwise_topk.  ``name`` is the
+    kernel's fixed name: the compiled custom call, and so its device op
+    in a profile, reads ``<name>.N`` whatever program holds it.  Returns
+    per-slot running lists ``(vals f32 [k, block, topk], idx i32 [k,
+    block, topk])``."""
     if metric not in QUERY_METRICS:
         raise ValueError(f"metric must be one of {QUERY_METRICS}, "
                          f"got {metric!r}")
@@ -268,6 +271,7 @@ def directed_topk_call(x, sq, lo, hi, meta, *, topk: int, block_rows: int,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name=name,
     )(*ins)
     return vals[:, :block], idx[:, :block]
 
@@ -287,4 +291,4 @@ def pairwise_topk_pallas(quorum: jax.Array, lo: jax.Array, hi: jax.Array,
     sq = jnp.sum(quorum * quorum, axis=-1)
     return directed_topk_call(quorum, sq, lo, hi, meta, topk=topk,
                               block_rows=block_rows, metric=metric,
-                              interpret=interpret)
+                              name="pairwise_topk", interpret=interpret)

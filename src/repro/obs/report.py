@@ -24,12 +24,18 @@ from pathlib import Path
 from typing import Any, Dict, List, Union
 
 __all__ = [
+    "CLOCKS",
     "load_trace",
     "validate_chrome_trace",
     "span_summary",
     "counter_summary",
     "render",
 ]
+
+#: what a ``ts`` counts: epoch microseconds (format version 2, the
+#: profiler's host clock) or microseconds since the tracer started
+#: (version 1 files)
+CLOCKS = ("epoch-us", "relative-us")
 
 
 def load_trace(path: Union[str, Path]) -> Dict[str, Any]:
@@ -48,8 +54,8 @@ def validate_chrome_trace(obj: Any) -> List[str]:
     of problems (empty == valid).  Checks the envelope (``traceEvents``
     list), each event's required fields (``name``/``ph``/``ts``; ``dur
     >= 0`` for ``ph="X"``; ``args.value`` for ``ph="C"``), and — when
-    the ``repro`` section is present — its version and counter shape
-    (DESIGN.md section 14.4)."""
+    the ``repro`` section is present — its version, clock and counter
+    shape (DESIGN.md section 14.4)."""
     errors: List[str] = []
     if not isinstance(obj, dict):
         return ["top level is not an object"]
@@ -81,6 +87,10 @@ def validate_chrome_trace(obj: Any) -> List[str]:
         else:
             if not isinstance(repro.get("version"), int):
                 errors.append("repro.version missing or not an int")
+            clock = repro.get("clock", "relative-us")
+            if clock not in CLOCKS:
+                errors.append(f"repro.clock {clock!r} is not one of "
+                              f"{sorted(CLOCKS)}")
             counters = repro.get("counters", {})
             if not isinstance(counters, dict):
                 errors.append("repro.counters is not an object")
@@ -147,6 +157,8 @@ def render(obj: Dict[str, Any]) -> str:
     n_ev = len(obj.get("traceEvents", []))
     lines.append(f"trace: {n_ev} events"
                  + (f", version {repro['version']}" if "version" in repro
+                    else "")
+                 + (f", clock {repro['clock']}" if "clock" in repro
                     else "")
                  + (f", meta={meta}" if meta else ""))
 

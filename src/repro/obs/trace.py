@@ -1,17 +1,31 @@
-"""Structured tracing + metrics for the sweep runtime (DESIGN.md
-section 14).
+"""Structured tracing + metrics for the sweep runtime and its host
+drivers (DESIGN.md section 14).
 
-One :class:`Tracer` collects two kinds of records:
+Two kinds of records:
 
-  * **spans** — named, nested wall-clock intervals with attributes
-    (mode, placement, P, round id, ...).  Two families by *when* the
-    code runs: trace-time spans (inside a jit trace — they measure the
-    Python tracing of a program, and their counters are exact because
-    collective shapes are static) and host-side runtime spans (the
-    fault-tolerant driver's rounds, serving per-query latency).
+  * **spans** — named, nested wall-clock intervals of *host* work with
+    attributes (rows, P, queue depth, ...): the k-NN entry point's
+    stage / upload / dispatch / wait / fetch, the batcher's idle, step
+    and launch phases, the fault driver's rounds.  One call site
+    (``with get_tracer().span(name, **attrs):``) feeds two sinks:
+
+      - while a JAX profiler session is collecting, the span is a
+        ``jax.profiler.TraceAnnotation`` carrying its attributes as
+        metadata, so it lands in the ``.xplane.pb`` on the same clock
+        as the device operations;
+      - while ``REPRO_TRACE`` is on, the :class:`Tracer` records it in
+        its Chrome-trace export, stamped in epoch microseconds
+        (``time.time_ns``), the clock the profiler stamps host events
+        with.
+
+    With neither on, the span is the shared no-op and leaves no
+    allocation behind.  Device-side phases (quorum gather, pair
+    compute, scatter) are not spans: they are ``jax.named_scope`` names
+    on the device operations' metadata (core/sweep.py).
   * **counters** — a monotonic value per (name, device) key; the
     comm-volume counters (``comm.ppermute.*``, ``comm.allgather.*``)
-    record **bytes per device** (the SPMD programs are symmetric), the
+    record **bytes per device**, counted at jit-trace time where the
+    collective shapes are static (the SPMD programs are symmetric), the
     driver counters record cluster totals.  The taxonomy is DESIGN.md
     section 14.2.
 
@@ -19,8 +33,9 @@ Activation (read through the ``core.env`` registry at call time, cached
 on the raw environment values):
 
   * ``REPRO_TRACE=0`` / unset — off: :func:`get_tracer` returns the
-    falsy :data:`NOOP` singleton and instrumented call sites early-out
-    (zero-cost: no span objects, no attribute dicts).
+    falsy :data:`NOOP` singleton; counter sites early-out behind
+    ``if tr:`` and span sites get the no-op (or, under a profiler
+    session, the profiler annotation).
   * ``REPRO_TRACE=1`` — on; the Chrome-trace JSON is written to
     ``repro_trace.json`` in the working directory at process exit.
   * ``REPRO_TRACE=<path>`` — on; written to ``<path>`` at exit.
@@ -32,8 +47,8 @@ with ``ph="X"`` complete events and ``ph="C"`` counter samples —
 loadable in Perfetto / chrome://tracing) plus a ``repro`` section
 carrying the raw counter totals for exact predictor comparison
 (``obs.comm``).  This module stays jax-free so the report CLI and the
-host drivers never pay a jax import for it; the optional
-``jax.profiler`` annotation hook imports lazily.
+host drivers never pay a jax import for it: it looks at the profiler
+only where the process has already imported jax.
 """
 
 from __future__ import annotations
@@ -41,6 +56,8 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
+import threading
 import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple, Union
@@ -59,10 +76,13 @@ __all__ = [
     "nbytes_of",
     "DEFAULT_TRACE_PATH",
     "TRACE_FORMAT_VERSION",
+    "TRACE_CLOCK",
 ]
 
 DEFAULT_TRACE_PATH = "repro_trace.json"
-TRACE_FORMAT_VERSION = 1
+TRACE_FORMAT_VERSION = 2
+#: the export's timestamps: epoch microseconds, the profiler's host clock
+TRACE_CLOCK = "epoch-us"
 
 
 def nbytes_of(x: Any) -> int:
@@ -87,10 +107,28 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _collecting():
+    """``jax.profiler.TraceAnnotation`` while a JAX profiler session is
+    collecting, else None.  No session can run in a process that has not
+    imported jax, so jax is never imported here."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    return prof.TraceAnnotation
+
+
+def _runtime_span(name: str, attrs: Dict[str, Any]):
+    """A span that only the profiler records: its annotation while a
+    session is collecting, else the shared no-op."""
+    ann = _collecting()
+    return _NOOP_SPAN if ann is None else ann(name, **attrs)
+
+
 class NoopTracer:
-    """The disabled tracer: falsy, so instrumented sites guard with
-    ``tr = get_tracer(); if tr: ...`` and pay nothing when tracing is
-    off (DESIGN.md section 14.1)."""
+    """The disabled tracer: falsy, so counter sites guard with ``tr =
+    get_tracer(); if tr: ...`` and pay nothing when tracing is off
+    (DESIGN.md section 14.1).  Its :meth:`span` still reaches a
+    collecting profiler session."""
 
     __slots__ = ()
     enabled = False
@@ -98,9 +136,10 @@ class NoopTracer:
     def __bool__(self) -> bool:
         return False
 
-    def span(self, name: str, **attrs) -> _NoopSpan:
-        """No-op span: returns the shared singleton context manager."""
-        return _NOOP_SPAN
+    def span(self, name: str, **attrs):
+        """The profiler annotation while a session is collecting, else
+        the shared no-op context manager."""
+        return _runtime_span(name, attrs)
 
     def record(self, name: str, dur_s: float = 0.0, **attrs) -> None:
         """No-op completed-span record."""
@@ -115,7 +154,8 @@ NOOP = NoopTracer()
 
 class _Span:
     """One live span interval (context manager); appended to the owning
-    tracer's event list on exit.  ``attrs`` is stored by reference, so
+    tracer's event list on exit, and an annotation of a collecting
+    profiler session meanwhile.  ``attrs`` is stored by reference, so
     code inside the ``with`` block may add result attributes."""
 
     __slots__ = ("tracer", "name", "device", "attrs", "start", "depth",
@@ -131,23 +171,20 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         tr = self.tracer
+        ann = _collecting()
+        if ann is not None:
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
         self.depth = len(tr._stack)
         if tr._stack:
             self.attrs.setdefault("parent", tr._stack[-1])
         tr._stack.append(self.name)
-        if tr.profiler:  # optional jax.profiler annotation hook
-            try:
-                from jax.profiler import TraceAnnotation
-                self._ann = TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:  # pragma: no cover - jax absent / old
-                self._ann = None
-        self.start = tr._now_us()
+        self.start = _now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self.tracer
-        end = tr._now_us()
+        end = _now_us()
         if self._ann is not None:
             self._ann.__exit__(*exc)
         tr._stack.pop()
@@ -162,35 +199,44 @@ class _Span:
         return False
 
 
+def _now_us() -> float:
+    """Epoch microseconds: the profiler stamps host events with
+    ``time.time_ns``, so exported spans line up with an ``.xplane.pb``."""
+    return time.time_ns() / 1e3
+
+
 class Tracer:
     """The enabled tracer: span + counter collection and Chrome-trace
     export (DESIGN.md section 14.1).
 
     ``path`` is where :meth:`export` writes by default (the env-driven
     tracer flushes there at process exit).  ``metrics_only`` drops span
-    events (the ``REPRO_METRICS`` mode).  ``profiler`` additionally
-    wraps every span in a ``jax.profiler.TraceAnnotation`` so spans
-    land in an XLA profile too (optional hook; lazily imported).
+    events (the ``REPRO_METRICS`` mode); a collecting profiler session
+    still gets the spans.
     """
 
     enabled = True
 
     def __init__(self, path: Optional[Union[str, Path]] = None,
-                 metrics_only: bool = False, profiler: bool = False):
+                 metrics_only: bool = False):
         self.path = Path(path) if path is not None else None
         self.metrics_only = metrics_only
-        self.profiler = profiler
         self.events: List[Dict[str, Any]] = []
         self.counters: Dict[Tuple[str, int], float] = {}
         self.meta: Dict[str, Any] = {}
-        self._stack: List[str] = []
-        self._t0 = time.perf_counter()
+        self._local = threading.local()
 
     def __bool__(self) -> bool:
         return True
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @property
+    def _stack(self) -> List[str]:
+        """The open spans of the calling thread (the batcher's loop
+        thread nests its own)."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, *, device: int = -1, **attrs):
@@ -198,10 +244,10 @@ class Tracer:
 
         ``device`` tags the span's pid lane (-1 = host / all devices);
         remaining keyword arguments become span attributes.  In
-        ``metrics_only`` mode spans are skipped entirely."""
+        ``metrics_only`` mode only a collecting profiler sees spans."""
         if self.metrics_only:
-            return _NOOP_SPAN
-        return _Span(self, name, device, dict(attrs))
+            return _runtime_span(name, attrs)
+        return _Span(self, name, device, attrs)
 
     def record(self, name: str, dur_s: float = 0.0, *, device: int = -1,
                **attrs) -> None:
@@ -209,12 +255,11 @@ class Tracer:
         (for call sites that measured themselves)."""
         if self.metrics_only:
             return
-        attrs = dict(attrs)
         attrs["depth"] = len(self._stack)
-        end = self._now_us()
+        end = _now_us()
         self.events.append({
             "name": name, "ph": "X",
-            "ts": max(0.0, end - dur_s * 1e6), "dur": dur_s * 1e6,
+            "ts": end - dur_s * 1e6, "dur": dur_s * 1e6,
             "pid": device if device >= 0 else 0, "tid": 0,
             "cat": "repro", "args": attrs,
         })
@@ -245,7 +290,7 @@ class Tracer:
         """The collected data as a Chrome-trace dict: span events plus
         one ``ph="C"`` counter sample per (name, device), and the raw
         totals under the ``repro`` key for exact comparison."""
-        now = self._now_us()
+        now = _now_us()
         events = list(self.events)
         for (name, dev), val in sorted(self.counters.items()):
             events.append({
@@ -261,7 +306,7 @@ class Tracer:
             "displayTimeUnit": "ms",
             "repro": {
                 "version": TRACE_FORMAT_VERSION,
-                "clock": "relative-us",
+                "clock": TRACE_CLOCK,
                 "counters": counters,
                 "meta": dict(self.meta),
             },
@@ -328,14 +373,12 @@ def get_tracer() -> Union[Tracer, NoopTracer]:
 
 
 def configure(path: Optional[Union[str, Path]] = None,
-              metrics_only: bool = False,
-              profiler: bool = False) -> Tracer:
+              metrics_only: bool = False) -> Tracer:
     """Programmatically activate a fresh :class:`Tracer` (overriding the
     environment selection) and return it — the test / selfcheck entry
     point (DESIGN.md section 14.1).  Pair with :func:`reset`."""
     global _forced
-    _forced = Tracer(path=path, metrics_only=metrics_only,
-                     profiler=profiler)
+    _forced = Tracer(path=path, metrics_only=metrics_only)
     return _forced
 
 

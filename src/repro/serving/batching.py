@@ -55,6 +55,7 @@ from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..core import env as env_mod
@@ -301,18 +302,12 @@ class BatchScheduler:
         with self._lock:
             if len(self._queue) >= self.max_queue:
                 self.counters["rejected"] += 1
-                tr = obs_trace.get_tracer()
-                if tr:
-                    tr.count("serving.sched.rejected")
                 raise AdmissionError(
                     f"request queue full ({self.max_queue} waiting); "
                     "shed load or raise REPRO_SERVE_QUEUE_DEPTH")
             self._queue.append(req)
             self.counters["admitted"] += 1
             self._wakeup.notify()
-        tr = obs_trace.get_tracer()
-        if tr:
-            tr.count("serving.sched.admitted")
         return req
 
     @property
@@ -340,12 +335,6 @@ class BatchScheduler:
         if self._t_first_done is None:
             self._t_first_done = now
         self._t_last_done = now
-        tr = obs_trace.get_tracer()
-        if tr:
-            tr.count(f"serving.sched.{res.status}")
-            tr.record("serving.request", dur_s=res.latency_s,
-                      kind=req.kind, metric=req.metric, status=res.status,
-                      rid=req.rid)
         req._event.set()
 
     def _expire(self, req: Request, now: float) -> None:
@@ -387,18 +376,14 @@ class BatchScheduler:
         for req in batch:
             groups.setdefault((req.kind, req.metric), []).append(req)
         tr = obs_trace.get_tracer()
-        span = tr.span("serving.sched.step", batch=len(batch),
-                       groups=len(groups), queue_depth=depth) if tr \
-            else obs_trace.NOOP.span("")
-        with span:
+        with tr.span("batch.step", n=len(batch), groups=len(groups),
+                     queue_depth=depth):
             for (kind, metric), reqs in groups.items():
                 self.counters["launches"] += 1
-                if tr:
-                    tr.count("serving.sched.launches")
                 if kind == "topk":
-                    resolved += self._launch_topk(reqs, metric)
+                    resolved += self._launch_topk(reqs, metric, tr)
                 else:
-                    resolved += self._launch_threshold(reqs, metric)
+                    resolved += self._launch_threshold(reqs, metric, tr)
         return resolved
 
     def _pack_queries(self, reqs: List[Request]) -> np.ndarray:
@@ -408,7 +393,32 @@ class BatchScheduler:
             q[i] = r.query
         return q
 
-    def _launch_topk(self, reqs: List[Request], metric: str) -> int:
+    def _run_launch(self, reqs: List[Request], tr, pack, dispatch):
+        """One launch's host phases, each a span (DESIGN.md section
+        14.1): ``pack()`` builds the payload, ``dispatch(payload)``
+        enqueues the program, then the scheduler waits for the device
+        and fetches the outputs to the host.  Adds each request's wait
+        from submission to the launch's start to ``queue_wait_s``, and
+        the launch's time outside ``batch.wait`` to ``launch_host_s``.
+        Returns the fetched outputs and the clock reading after the
+        fetch (the requests' completion time)."""
+        t0 = self._clock()
+        self.counters["queue_wait_s"] += sum(t0 - r.t_submit for r in reqs)
+        with tr.span("batch.pack", n=len(reqs)):
+            payload = pack()
+        with tr.span("batch.dispatch"):
+            out = dispatch(payload)
+        t_wait = self._clock()
+        with tr.span("batch.wait"):
+            jax.block_until_ready(out)
+        t_ready = self._clock()
+        with tr.span("batch.fetch"):
+            out = tuple(np.asarray(a) for a in out)
+        now = self._clock()
+        self.counters["launch_host_s"] += (t_wait - t0) + (now - t_ready)
+        return out, now
+
+    def _launch_topk(self, reqs: List[Request], metric: str, tr) -> int:
         """One padded top-k launch at the pow2 bucket of the largest
         requested k; per-request rows sliced back to their own k —
         exact by the total-order prefix property (DESIGN.md 15.2)."""
@@ -416,20 +426,22 @@ class BatchScheduler:
         self.program_keys.add(
             ("topk", metric, self.mode, quantize_pow2(kmax),
              self.use_kernel))
-        q = self._pack_queries(reqs)
-        vals, idx = self.corpus.query(q, topk=kmax, mode=self.mode,
-                                      metric=metric,
-                                      use_kernel=self.use_kernel)
-        vals, idx = np.asarray(vals), np.asarray(idx)   # block until ready
-        now = self._clock()
-        for i, r in enumerate(reqs):
-            self._resolve(r, RequestResult(
-                status="done", scores=vals[i, :r.topk].copy(),
-                indices=idx[i, :r.topk].copy(), count=None,
-                latency_s=now - r.t_submit), now)
+        (vals, idx), now = self._run_launch(
+            reqs, tr, lambda: self._pack_queries(reqs),
+            lambda q: self.corpus.query(q, topk=kmax, mode=self.mode,
+                                        metric=metric,
+                                        use_kernel=self.use_kernel))
+        with tr.span("batch.resolve", n=len(reqs)):
+            for i, r in enumerate(reqs):
+                self._resolve(r, RequestResult(
+                    status="done", scores=vals[i, :r.topk].copy(),
+                    indices=idx[i, :r.topk].copy(), count=None,
+                    latency_s=now - r.t_submit), now)
+        self.counters["launch_host_s"] += self._clock() - now
         return len(reqs)
 
-    def _launch_threshold(self, reqs: List[Request], metric: str) -> int:
+    def _launch_threshold(self, reqs: List[Request], metric: str,
+                          tr) -> int:
         """One padded range-query launch: per-query threshold vector
         (padding rows get +inf, matching nothing), capacity = the
         group max on the pow2 ladder.  Overflowing requests re-enter
@@ -437,50 +449,53 @@ class BatchScheduler:
         in which case the truncated buffer returns as a partial result
         (DESIGN.md sections 15.2, 15.3)."""
         cap_req = max(r.capacity or self._default_capacity for r in reqs)
-        q = self._pack_queries(reqs)
-        thr = np.full((q.shape[0],), np.inf, np.float32)
-        for i, r in enumerate(reqs):
-            thr[i] = r.threshold
-        vals, idx, cnt = self.corpus.query_threshold(
-            q, threshold=thr, capacity=cap_req, mode=self.mode,
-            metric=metric, escalate=False)
-        vals, idx = np.asarray(vals), np.asarray(idx)
-        cnt = np.asarray(cnt)
+
+        def pack():
+            q = self._pack_queries(reqs)
+            thr = np.full((q.shape[0],), np.inf, np.float32)
+            for i, r in enumerate(reqs):
+                thr[i] = r.threshold
+            return q, thr
+
+        (vals, idx, cnt), now = self._run_launch(
+            reqs, tr, pack,
+            lambda qt: self.corpus.query_threshold(
+                qt[0], threshold=qt[1], capacity=cap_req, mode=self.mode,
+                metric=metric, escalate=False))
         cap_used = vals.shape[1]
         self.program_keys.add(("threshold", metric, self.mode, cap_used))
-        now = self._clock()
         total = self.corpus.P * self.corpus.block
         resolved = 0
         requeue: List[Request] = []
-        tr = obs_trace.get_tracer()
-        for i, r in enumerate(reqs):
-            n = int(cnt[i])
-            if n <= cap_used:
+        with tr.span("batch.resolve", n=len(reqs)):
+            for i, r in enumerate(reqs):
+                n = int(cnt[i])
+                if n <= cap_used:
+                    self._resolve(r, RequestResult(
+                        status="done", scores=vals[i, :n].copy(),
+                        indices=idx[i, :n].copy(), count=n,
+                        latency_s=now - r.t_submit), now)
+                    resolved += 1
+                    continue
+                # overflow: escalate along the pow2 ladder, deadline allowing
+                out_of_time = (r.t_deadline is not None
+                               and now > r.t_deadline)
+                if (not out_of_time and cap_used < total
+                        and r.escalations < self.max_escalations):
+                    r.escalations += 1
+                    r.capacity = min(2 * cap_used, total)
+                    self.counters["escalations"] += 1
+                    requeue.append(r)
+                    continue
                 self._resolve(r, RequestResult(
-                    status="done", scores=vals[i, :n].copy(),
-                    indices=idx[i, :n].copy(), count=n,
+                    status="partial", scores=vals[i].copy(),
+                    indices=idx[i].copy(), count=n,
                     latency_s=now - r.t_submit), now)
                 resolved += 1
-                continue
-            # overflow: escalate along the pow2 ladder, deadline allowing
-            out_of_time = (r.t_deadline is not None and now > r.t_deadline)
-            if (not out_of_time and cap_used < total
-                    and r.escalations < self.max_escalations):
-                r.escalations += 1
-                r.capacity = min(2 * cap_used, total)
-                self.counters["escalations"] += 1
-                if tr:
-                    tr.count("serving.sched.escalations")
-                requeue.append(r)
-                continue
-            self._resolve(r, RequestResult(
-                status="partial", scores=vals[i].copy(),
-                indices=idx[i].copy(), count=n,
-                latency_s=now - r.t_submit), now)
-            resolved += 1
-        if requeue:
-            with self._lock:
-                self._queue.extendleft(reversed(requeue))
+            if requeue:
+                with self._lock:
+                    self._queue.extendleft(reversed(requeue))
+        self.counters["launch_host_s"] += self._clock() - now
         return resolved
 
     # -------------------------------------------------------------- lifecycle
@@ -507,8 +522,10 @@ class BatchScheduler:
         def loop():
             while True:
                 with self._lock:
-                    while not self._queue and not self._stopping:
-                        self._wakeup.wait(timeout=0.05)
+                    if not self._queue and not self._stopping:
+                        with obs_trace.get_tracer().span("batch.idle"):
+                            while not self._queue and not self._stopping:
+                                self._wakeup.wait(timeout=0.05)
                     if self._stopping and not self._queue:
                         return
                 self.step()

@@ -659,10 +659,9 @@ class ServingCorpus:
         order: the first k entries of a top-K list *are* the top-k list.
         Heterogeneous k therefore share one program per bucket.
 
-        With tracing on, each call is a ``serving.query`` host span
-        (blocked until the result is device-complete, so the span is
-        true end-to-end latency) and a ``serving.queries`` counter
-        (DESIGN.md section 14.2).
+        Each call is a ``serving.query`` host span around the dispatch
+        (it does not wait for the device) and, with tracing on, a
+        ``serving.queries`` counter (DESIGN.md section 14).
 
         A corpus built with ``quant != "off"`` scores against its
         quantized resident stack and rescores the certified candidates
@@ -685,13 +684,10 @@ class ServingCorpus:
                        use_kernel, self.placement)
         q = jnp.asarray(queries, jnp.float32)
         tr = obs_trace.get_tracer()
-        if not tr:
+        with tr.span("serving.query", Q=int(q.shape[0]), topk=topk,
+                     mode=mode, metric=metric, P=self.P):
             out = run(q, self.state)
-        else:
-            with tr.span("serving.query", Q=int(q.shape[0]), topk=topk,
-                         mode=mode, metric=metric, P=self.P):
-                out = run(q, self.state)
-                jax.block_until_ready(out)
+        if tr:
             tr.count("serving.queries", int(q.shape[0]))
         if kq == topk:
             return out
@@ -731,17 +727,16 @@ class ServingCorpus:
         q = jnp.asarray(queries, jnp.float32)
         escalations = 0
         tr = obs_trace.get_tracer()
-        span = tr.span("serving.query_threshold", Q=int(q.shape[0]),
-                       mode=mode, metric=metric, P=self.P) if tr \
-            else obs_trace.NOOP.span("")
-        with span:
+        with tr.span("serving.query_threshold", Q=int(q.shape[0]),
+                     mode=mode, metric=metric, P=self.P):
             while True:
                 run = threshold_fn(self.mesh, self.axis_name, cap, mode,
                                    metric, self.placement)
                 vals, idx, cnt = run(q, threshold, self.state)
+                if not escalate:    # the caller reads the counts
+                    break
                 counts = np.asarray(cnt)
-                if (not (counts > cap).any() or not escalate
-                        or cap >= total_rows
+                if (not (counts > cap).any() or cap >= total_rows
                         or escalations >= max_doublings):
                     break
                 cap = min(2 * cap, total_rows)

@@ -36,12 +36,27 @@ def test_validate_accepts_tracer_output():
     (lambda o: o["traceEvents"][0].update(dur=-1.0), "ph=X needs dur"),
     (lambda o: o["repro"].update(version="x"), "repro.version"),
     (lambda o: o["repro"].update(counters=[1]), "repro.counters"),
+    (lambda o: o["repro"].update(clock="ticks"), "repro.clock"),
 ])
 def test_validate_flags_malformed(mutate, needle):
     obj = _sample_trace()
     mutate(obj)
     errors = report_mod.validate_chrome_trace(obj)
     assert errors and any(needle in e for e in errors), errors
+
+
+@pytest.mark.parametrize("version,clock", [(2, "epoch-us"),
+                                           (1, "relative-us"), (1, None)])
+def test_validate_accepts_both_clocks(version, clock):
+    """Format 2 stamps epoch microseconds; a version-1 file (relative
+    microseconds, with or without the clock field) still validates."""
+    obj = _sample_trace()
+    obj["repro"]["version"] = version
+    if clock is None:
+        del obj["repro"]["clock"]
+    else:
+        obj["repro"]["clock"] = clock
+    assert report_mod.validate_chrome_trace(obj) == []
 
 
 def test_validate_counter_sample_needs_value():

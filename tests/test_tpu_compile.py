@@ -126,3 +126,35 @@ def test_knn_sweep_compiles_inside_shard_map(topo, monkeypatch):
     _compile_for_tpu(
         fn, jax.ShapeDtypeStruct((KNN_BLOCK, D), jnp.float32, sharding=rows),
         jax.ShapeDtypeStruct((1, sched.n_pairs), jnp.float32, sharding=rows))
+
+
+@pytest.mark.parametrize("P", [1, 4])
+def test_knn_program_names_its_kernel_and_phases(topo, monkeypatch, P):
+    """The whole ``knn_graph`` program at P = 1 and at P = 4 cyclic: its
+    kernel's custom call reads ``%pairwise_topk.N`` whatever program
+    holds it, and the sweep's device ops carry the ``sweep.*`` named
+    scopes in their ``op_name``."""
+    import re
+    from repro.core import knn
+    from repro.core.placement import resolve_placement
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = make_mesh((P,), ("q",), devices=topo.devices[:P])
+    block = 4096
+    run = knn._knn_fn(mesh, "q", P * block, block, 10, "l2", "batched", True,
+                      resolve_placement("cyclic", P))
+    cells = [c.cell_contents for c in run.__closure__]
+    fn = next(c for c in cells if hasattr(c, "lower"))
+    mask_table = next(c for c in cells if hasattr(c, "shape"))
+    rows = NamedSharding(mesh, PS("q"))
+    text = _compile_for_tpu(
+        fn, jax.ShapeDtypeStruct((P * block, D), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct(mask_table.shape, mask_table.dtype,
+                             sharding=rows)).as_text()
+    calls = re.findall(r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", text,
+                       re.M)
+    assert calls and all(c.startswith("pairwise_topk.") for c in calls), calls
+    scopes = {"sweep.pair_compute"} | ({"sweep.gather", "sweep.scatter"}
+                                       if P > 1 else set())
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
