@@ -282,7 +282,9 @@ def quorum_allpairs_knn(
     generic pipeline over its A = {0..P-1} shifts.  ``mode`` is the
     runtime's batched/overlap/scan surface (``REPRO_ALLPAIRS_MODE``
     honored); ``batch_fn(quorum, lo, hi, meta) -> (vals, idx)`` is the
-    fused-kernel hook (kernels.ops.pairwise_topk), batched mode only.
+    fused-kernel hook (kernels.ops.pairwise_topk), batched mode only.  A
+    hook that returns more (the kernel's merge counts, ``count_rounds``)
+    has the rest appended to the result unchanged.
     """
     if metric not in KNN_METRICS:
         raise ValueError(f"metric must be one of {KNN_METRICS}, "
@@ -309,13 +311,13 @@ def quorum_allpairs_knn(
     emitter = KnnEmitter(schedule, mask, topk, metric, block, axis_name,
                          (lo, hi, ga, gb, nv_lo, nv_hi, is_self),
                          batch_fn=batch_fn)
-    vals, idx = sweep_mod.pair_sweep(emitter, schedule=schedule,
-                                     axis_name=axis_name, mode=mode, x=x)
+    vals, idx, *extra = sweep_mod.pair_sweep(
+        emitter, schedule=schedule, axis_name=axis_name, mode=mode, x=x)
     partials = [(vals[s], idx[s]) for s in range(schedule.k)]
     mv, mi = quorum_scatter(
         partials, schedule, axis_name,
         reduce_fn=lambda a, b: _merge_lists(a[0], a[1], b[0], b[1], topk))
-    return mv, mi
+    return (mv, mi, *extra)
 
 
 # ---------------------------------------------------------------------------
@@ -344,9 +346,12 @@ class KnnResult:
 
 @functools.lru_cache(maxsize=64)
 def _knn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
-            metric: str, mode: str, use_kernel: bool, placement):
+            metric: str, mode: str, use_kernel: bool, placement,
+            count_rounds: bool = False):
     """Build (and cache) the jitted distributed k-NN program — one trace
-    per (mesh, shape, topk, ...) key, reused across repeated graphs."""
+    per (mesh, shape, topk, ...) key, reused across repeated graphs.
+    ``count_rounds`` (kernel only) adds a third output, each device's
+    kernel merge counts [k, row tiles, 2] stacked over the devices."""
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
@@ -358,7 +363,8 @@ def _knn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
                 f"use_kernel needs the batched mode (got mode={mode!r}); "
                 "the fused kernel only replaces the batched inner step")
         batch_fn = functools.partial(kops.pairwise_topk, topk=topk,
-                                     block_rows=block, metric=metric)
+                                     block_rows=block, metric=metric,
+                                     count_rounds=count_rounds)
 
     def body(xb, mb):
         return quorum_allpairs_knn(
@@ -367,7 +373,8 @@ def _knn_fn(mesh, axis_name: str, N: int, block: int, topk: int,
 
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+        body, mesh=mesh, in_specs=(spec, spec),
+        out_specs=(spec,) * (3 if count_rounds else 2),
         check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda xs: fn(xs, mask_table)
 
@@ -416,14 +423,22 @@ def knn_graph(corpus, mesh, *, topk: int, axis_name: str = "q",
         # blocks until the rows are on the chips, so that the span holds
         # the transfer; the program could not start before it anyway
         xs = jax.block_until_ready(shard_rows(x, mesh, axis_name))
+    # with the Tracer on, the kernel also counts its merge rounds; the
+    # program the Tracer does not see neither computes nor fetches them
+    count = bool(tr) and use_kernel
     with tr.span("knn.dispatch", rows=N, P=P):
         run = _knn_fn(mesh, axis_name, N, block, int(topk), metric, mode,
-                      use_kernel, plc)
+                      use_kernel, plc, count)
         out = run(xs)
     with tr.span("knn.wait", rows=N, P=P):
         jax.block_until_ready(out)
     with tr.span("knn.fetch", rows=N, P=P):
-        vals, idx = (np.asarray(a) for a in out)
+        vals, idx, *counts = (np.asarray(a) for a in out)
+    if counts:
+        per_dev = counts[0].reshape(P, -1, 2).sum(axis=1)
+        for d, (rounds, steps) in enumerate(per_dev):
+            tr.count("pairwise_topk.merge_rounds", int(rounds), device=d)
+            tr.count("pairwise_topk.merge_steps", int(steps), device=d)
     return KnnResult(indices=idx[:N], scores=vals[:N], topk=int(topk))
 
 

@@ -182,7 +182,8 @@ def pairwise_threshold(quorum, lo, hi, meta, *, threshold, capacity,
             count.reshape(()))
 
 
-def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot"):
+def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot",
+                  count_rounds=False):
     """Fused pair-scoring top-k step for the k-NN graph engine's
     ``batch_fn`` hook (core/knn.py; DESIGN.md section 12.3).
 
@@ -194,6 +195,8 @@ def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot"):
     global block stride for row-id math.  Returns the per-slot running top-k
     ``(vals f32 [k, block, topk], idx i32 [k, block, topk])`` under the
     engine's (-score, index) order — bit-parity with ref.pairwise_topk.
+    ``count_rounds`` adds a third output, the kernel's merge counts
+    (kernels/pairwise_topk.py ``directed_topk_call``).
 
     The kernel zero-pads block rows up to its tile multiple — exact, the
     valid-row bounds in ``meta`` reject them as candidates and the padded
@@ -202,6 +205,7 @@ def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot"):
     from .pairwise_topk import pairwise_topk_pallas
     return pairwise_topk_pallas(quorum, lo, hi, meta, topk=topk,
                                 block_rows=block_rows, metric=metric,
+                                count_rounds=count_rounds,
                                 interpret=_interpret())
 
 

@@ -1,5 +1,7 @@
 """Per-kernel allclose sweeps: Pallas (interpret mode on CPU) vs ref.py."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -229,3 +231,160 @@ def test_pairwise_topk_kernel(k, block, d, n_pairs, topk, metric):
     np.testing.assert_allclose(np.asarray(got_v),
                                np.asarray(want_v)[:, :block],
                                rtol=1e-5, atol=1e-5)
+
+
+def _merge_case(case, dtype=np.float32):
+    """(blocks [k, B, d], lo, hi, meta) of one merge-order case of the
+    gated k-NN kernel: every row list sees several 512-column steps."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    quorum4 = case in ("cyclic4", "duplicates")
+    k, B, d = (3, 1024, 16) if quorum4 else (1, 1024, 16)
+    if case == "random":
+        B = 2048
+        x = rng.normal(size=(k, B, d))
+    elif case == "wide":           # wide column tiles over a ragged block
+        B, d = 2048, 8
+        x = rng.normal(size=(k, B, d))
+    elif case == "ascending":      # every later column scores higher
+        x = np.zeros((k, B, d))
+        x[..., 0] = 1.0 + np.arange(B) / B
+    elif case == "duplicates":     # scores tie at the k-th entry, and
+        # later items bring lower indices: only the tie-break admits them
+        x = rng.normal(size=(48, d))[rng.integers(0, 48, (k, B))]
+    else:
+        x = rng.normal(size=(k, B, d))
+    if dtype == np.int8:
+        x = np.clip(np.round(x * 40), -127, 127)
+    if quorum4:                    # a self tile, two cross pairs, a dedup
+        lo = np.array([0, 0, 0, 1], np.int32)
+        hi = np.array([0, 1, 2, 2], np.int32)
+        blocks = np.array([3, 0, 1], np.int32)
+        nv = np.array([B, B, B - 100], np.int32)
+        meta = np.stack([[1, 1, 1, 0], lo == hi, blocks[lo], blocks[hi],
+                         nv[lo], nv[hi]], axis=1)
+    else:                          # one self tile (P = 1)
+        lo = hi = np.zeros(1, np.int32)
+        # fewer candidates than topk; a ragged block
+        nv = {"underfull": 6, "wide": B - 37}.get(case, B)
+        meta = np.array([[1, 1, 0, 0, nv, nv]])
+    return x.astype(dtype), lo, hi, meta.astype(np.int32)
+
+
+GATED_CASES = [("random", "l2"), ("random", "dot"), ("wide", "dot"),
+               ("ascending", "dot"),
+               ("duplicates", "l2"), ("duplicates", "dot"),
+               ("underfull", "l2"), ("cyclic4", "l2"), ("cyclic4", "dot")]
+
+
+@pytest.mark.parametrize("case,metric", GATED_CASES,
+                         ids=[f"{c}-{m}" for c, m in GATED_CASES])
+def test_pairwise_topk_kernel_merge_orders(case, metric, monkeypatch):
+    """The threshold-gated merge keeps ref.pairwise_topk's lists exactly
+    whatever the candidate order: random, random over wide column tiles
+    with a ragged block, ascending (every step runs all rounds), tied
+    scores at the k-th entry (index breaks the tie), fewer valid
+    candidates than topk, and a slot receiving several items (the cyclic
+    P = 4 layout, with a dedup-masked item)."""
+    x, lo, hi, meta = _merge_case(case)
+    if case == "wide":  # the tiling of a large block, at a small size
+        from repro.kernels import pairwise_topk as ptk
+        monkeypatch.setattr(ptk, "tile_plan", functools.partial(
+            ptk.tile_plan, rows=64, cols=64, wide=256))
+        assert ptk.tile_plan(x.shape[1]) == (64, 256, 2048)
+    quorum = jnp.asarray(x)
+    got_v, got_i = ops.pairwise_topk(quorum, lo, hi, jnp.asarray(meta),
+                                     topk=10, block_rows=x.shape[1],
+                                     metric=metric)
+    want_v, want_i = ref.pairwise_topk(quorum, lo, hi, meta, topk=10,
+                                       block_rows=x.shape[1], metric=metric)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    if metric == "dot":
+        np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    else:  # the CPU oracle contracts its l2 epilogue differently
+        np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v),
+                                   rtol=1e-6, atol=1e-5)
+
+
+QUANT_CASES = [("random", "dot"), ("ascending", "dot"), ("duplicates", "l2"),
+               ("underfull", "dot"), ("cyclic4", "l2")]
+
+
+@pytest.mark.parametrize("case,metric", QUANT_CASES,
+                         ids=[f"{c}-{m}" for c, m in QUANT_CASES])
+def test_pairwise_topk_q_kernel_merge_orders(case, metric):
+    """The quantized k-NN kernel runs the same gated merge: its lists
+    equal ref.pairwise_topk_q's in every merge-order case (int8 blocks
+    tie often on their own)."""
+    q, lo, hi, meta = _merge_case(case, np.int8)
+    k, B, _ = q.shape
+    scale = np.linspace(0.01, 0.02, k).astype(np.float32)
+    sd = np.stack([scale, np.zeros(k, np.float32)], axis=1)
+    sq = (q.astype(np.float32) * scale[:, None, None]) ** 2
+    sq = sq.sum(-1).astype(np.float32)
+    got_v, got_i = ops.pairwise_topk_q(
+        jnp.asarray(q), jnp.asarray(sd), jnp.asarray(sq), lo, hi,
+        jnp.asarray(meta), topk=10, block_rows=B, metric=metric)
+    want_v, want_i = ref.pairwise_topk_q(q, scale, sq, lo, hi, meta,
+                                         topk=10, block_rows=B,
+                                         metric=metric)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    if metric == "dot":
+        np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    else:
+        np.testing.assert_allclose(np.asarray(got_v), np.asarray(want_v),
+                                   rtol=1e-6, atol=1e-5)
+
+
+@pytest.mark.parametrize("block,plan", [
+    (7, (8, 8, 8)), (512, (512, 512, 512)), (513, (256, 512, 1024)),
+    (8191, (256, 512, 8192)), (8192, (256, 1024, 8192)),
+    (65_536, (256, 2048, 65_536)), (131_072, (256, 2048, 131_072)),
+    (70_000, (256, 2048, 71_680))])
+def test_tile_plan(block, plan):
+    """Blocks up to 512 rows are one tile; larger ones take 256-row tiles
+    and the widest column tile up to 2,048 that pads at most an eighth
+    of the block."""
+    from repro.kernels.pairwise_topk import tile_plan
+    tr, tc, bp = tile_plan(block)
+    assert (tr, tc, bp) == plan
+    assert bp % tr == 0 and bp % tc == 0 and bp >= block
+    assert bp == -(-block // 8) * 8 or bp - block <= max(block // 8, tc)
+
+
+def _round_counts(x, lo, hi, meta, metric):
+    from repro.kernels.pairwise_topk import pairwise_topk_pallas
+    *_, counts = pairwise_topk_pallas(
+        jnp.asarray(x), lo, hi, jnp.asarray(meta), topk=10,
+        block_rows=x.shape[1], metric=metric, count_rounds=True,
+        interpret=True)
+    return np.asarray(counts)                      # [k, row tiles, 2]
+
+
+def test_pairwise_topk_round_counts_random_order():
+    """Random order: a row list fed 64 column steps (one receiving slot,
+    64 sender blocks) needs at most 4 extraction rounds a step on
+    average at topk 10, against the 10 of an ungated merge."""
+    rng = np.random.default_rng(3)
+    J, B = 64, 512
+    x = rng.normal(size=(J + 1, B, 8)).astype(np.float32)
+    lo = np.zeros(J, np.int32)
+    hi = np.arange(1, J + 1, dtype=np.int32)
+    meta = np.stack([np.ones(J), np.zeros(J), lo, hi, np.full(J, B),
+                     np.full(J, B)], axis=1).astype(np.int32)
+    counts = _round_counts(x, lo, hi, meta, "dot")
+    rounds, steps = counts[0].sum(axis=0)
+    assert steps == J
+    assert rounds / steps <= 4, (rounds, steps)
+    # each sender slot receives one item into an empty list: 10 rounds
+    np.testing.assert_array_equal(counts[1:, 0], [[10, 1]] * J)
+
+
+def test_pairwise_topk_round_counts_ascending_order():
+    """Ascending order: every candidate tile beats the whole list, so
+    every tile step runs all topk rounds."""
+    from repro.kernels.pairwise_topk import tile_plan
+    x, lo, hi, meta = _merge_case("ascending")
+    counts = _round_counts(x, lo, hi, meta, "dot")
+    tr, tc, bp = tile_plan(x.shape[1])
+    nrt, ntc = bp // tr, bp // tc
+    np.testing.assert_array_equal(counts[0], [[10 * ntc, ntc]] * nrt)

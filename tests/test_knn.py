@@ -145,3 +145,81 @@ def test_knn_argument_validation():
     with pytest.raises(ValueError, match="batch_fn"):
         quorum_allpairs_knn(jnp.zeros((4, 3)), topk=2, axis_name="q",
                             axis_size=2, mode="scan", batch_fn=lambda *a: a)
+
+
+def test_knn_graph_counts_merge_rounds_under_the_tracer():
+    """With the Tracer on, ``knn_graph`` runs the kernel with its round
+    count and records ``pairwise_topk.merge_rounds`` and ``.merge_steps``
+    per device: at P = 4 cyclic every device's active directed items sum
+    to P^2 one-tile steps (a cross pair is scored in both orientations),
+    each running 1 to topk rounds; the graph is the untraced one."""
+    out = run_sub("""
+import numpy as np, jax
+from repro.core.knn import brute_force_knn, knn_graph
+from repro.launch.mesh import make_mesh
+from repro.obs import trace
+rng = np.random.default_rng(4)
+for P in (1, 4):
+    mesh = make_mesh((P,), ("q",), devices=jax.devices()[:P])
+    corpus = rng.normal(size=(P * 64 - 5, 8)).astype(np.float32)
+    run = lambda: knn_graph(corpus, mesh, topk=10, metric="l2",
+                            mode="batched", placement="cyclic",
+                            use_kernel=True, quant="off")
+    plain = run()
+    tr = trace.configure(metrics_only=True)
+    try:
+        got = run()
+    finally:
+        trace.reset()
+    np.testing.assert_array_equal(got.indices, plain.indices)
+    np.testing.assert_array_equal(
+        got.indices, brute_force_knn(corpus, 10, "l2").indices)
+    steps = tr.counters_by_device("pairwise_topk.merge_steps")
+    rounds = tr.counters_by_device("pairwise_topk.merge_rounds")
+    assert sorted(steps) == sorted(rounds) == list(range(P)), (steps, rounds)
+    s, r = sum(steps.values()), sum(rounds.values())
+    assert s == P * P and s <= r <= 10 * s, (P, steps, rounds)
+    print("COUNTS", P, s, r)
+""", 4)
+    assert out.count("COUNTS") == 2, out
+
+
+def test_knn_program_has_no_round_count_with_the_tracer_off():
+    """The program the untraced ``knn_graph`` runs has no count output:
+    its kernel returns the two lists alone, and the traced program's
+    kernel the counts as well."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import knn
+    from repro.core.placement import resolve_placement
+
+    mesh = jax.make_mesh((1,), ("q",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+
+    def kernel_outputs(count_rounds):
+        run = knn._knn_fn(mesh, "q", 600, 600, 10, "l2", "batched", True,
+                          resolve_placement("cyclic", 1), count_rounds)
+        cells = [c.cell_contents for c in run.__closure__]
+        fn = next(c for c in cells if hasattr(c, "lower"))
+        mask = next(c for c in cells if hasattr(c, "shape"))
+        jaxpr = jax.make_jaxpr(fn)(jnp.zeros((600, 8), jnp.float32), mask)
+        return len(jaxpr.out_avals), [len(e.outvars)
+                                      for e in _eqns(jaxpr.jaxpr)
+                                      if e.primitive.name == "pallas_call"]
+
+    assert kernel_outputs(False) == (2, [2])
+    assert kernel_outputs(True) == (3, [3])
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    from jax.extend import core as jcore
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _eqns(sub)

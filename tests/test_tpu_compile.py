@@ -65,6 +65,26 @@ def test_pairwise_topk_compiles(one_chip):
                      spec((1, 6), jnp.int32))
 
 
+@pytest.mark.parametrize("count_rounds", [False, True],
+                         ids=["count-off", "count-on"])
+@pytest.mark.parametrize("P", [1, 4])
+def test_gated_pairwise_topk_compiles_at_the_cell_shapes(one_chip, P,
+                                                         count_rounds):
+    """The gated merge, with the round count off and on, at the k-NN
+    cells' per-chip shapes: one 131,072-row block at P = 1, a 3-slot
+    quorum of 65,536-row blocks (a self tile, two cross pairs) at P = 4."""
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    k, block = (1, 131_072) if P == 1 else (3, 65_536)
+    lo = np.zeros(k, np.int32)
+    hi = np.arange(k, dtype=np.int32)
+    fn = lambda x, meta: pairwise_topk_pallas(
+        x, lo, hi, meta, topk=10, block_rows=block, metric="l2",
+        count_rounds=count_rounds)
+    _compile_for_tpu(fn, spec((k, block, D), jnp.float32),
+                     spec((k, 6), jnp.int32))
+
+
 def test_query_topk_compiles(one_chip):
     # ServingCorpus runs top-10 requests on the pow2 bucket topk = 16
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
