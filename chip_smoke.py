@@ -248,8 +248,7 @@ def phase_serve(clock, rng, *, N, d, P, n_queries=256, topk=10,
 
 def phase_join(clock, rng, *, N, d, P=1, dup_frac=0.01, threshold=0.9):
     """Thresholded self-join over unit-norm rows with planted
-    near-duplicates, in the batched jnp mode (the fused join kernel does
-    not lower for TPU)."""
+    near-duplicates, through the fused join kernel in the batched mode."""
     from repro.core.sparse import similarity_join
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((P,), ("q",))
@@ -261,7 +260,7 @@ def phase_join(clock, rng, *, N, d, P=1, dup_frac=0.01, threshold=0.9):
     x /= np.linalg.norm(x, axis=1, keepdims=True)
     run = lambda: similarity_join(x, mesh, threshold=threshold, metric="dot",
                                   mode="batched", placement="cyclic",
-                                  use_kernel=False, quant="off")
+                                  use_kernel=True, quant="off")
     first, c_s, first_s = timed(clock, run)
     got, _, run_s = timed(clock, run)
     np.testing.assert_array_equal(first.i, got.i)
@@ -282,7 +281,7 @@ def phase_join(clock, rng, *, N, d, P=1, dup_frac=0.01, threshold=0.9):
             f"{len(want - have)} missing pairs against the reference")
     err = np.abs(got.scores.astype(np.float64) - s[got.i, got.j])
     report("join", N=N, d=d, P=P, placement="cyclic", threshold=threshold,
-           metric="dot", mode="batched", kernel="none",
+           metric="dot", mode="batched", kernel="pairwise_threshold",
            pairs=len(have), planted=n_dup, compile_s=c_s, first_s=first_s,
            run_s=run_s, rows_checked=N, near_tie_pairs=int(ties.sum()),
            max_abs_err=float(err.max(initial=0.0)))

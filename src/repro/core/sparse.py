@@ -82,9 +82,15 @@ __all__ = [
 
 JOIN_METRICS = ("dot", "l2")
 
-# global row ids ride through the fused kernel's one-hot matmul compaction
-# as exact float32 integers, which caps the corpus size (DESIGN.md 11.2)
+# global row ids ride through the quantized join kernel's one-hot matmul
+# compaction as exact float32 integers, which caps its corpus size
+# (DESIGN.md 11.2; the f32 kernel carries int32 ids)
 MAX_ROWS_F32_EXACT = 1 << 24
+# the starting capacity's three per-device buffers (12 B a slot) take at
+# most this share of a device's memory (DESIGN.md 11.2)
+START_MEMORY_SHARE = 1 / 1024
+# a device's memory where the backend does not report it: a TPU v5e's
+DEFAULT_DEVICE_BYTES = 16 << 30
 
 
 class SparseHits(NamedTuple):
@@ -96,12 +102,22 @@ class SparseHits(NamedTuple):
             hold ``IDX_SENTINEL``.
     count : [] int32 — the TRUE number of passing pairs on this device
             (may exceed capacity; see the overflow contract above).
+    stats : [2] int32 or None — with the fused kernel, (tile steps that
+            emitted, hits dropped by a full slot row); None elsewhere.
     """
 
     vals: jax.Array
     i: jax.Array
     j: jax.Array
     count: jax.Array
+    stats: Optional[jax.Array] = None
+
+
+def _device_bytes() -> int:
+    """The first device's memory, or a TPU v5e's where the backend does
+    not report it (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEFAULT_DEVICE_BYTES))
 
 
 def default_capacity(n_candidates: int) -> int:
@@ -109,15 +125,17 @@ def default_capacity(n_candidates: int) -> int:
 
     ``REPRO_SPARSE_CAPACITY`` (documented in the README env-var table;
     validated through the core/env.py registry) overrides; otherwise 1/8
-    of the device's candidate count, rounded up to a lane-friendly
-    multiple of 128 with a floor of 128.  Read at selection time like
-    the other ``REPRO_*`` knobs, and only a *start*:
+    of the device's candidate count, but no more slots than
+    ``START_MEMORY_SHARE`` of the device's memory holds, rounded up to a
+    lane-friendly multiple of 128 with a floor of 128.  Read at
+    selection time like the other ``REPRO_*`` knobs, and only a *start*:
     :func:`similarity_join` doubles it until the overflow flag clears.
     """
     cap = env_mod.read_knob("REPRO_SPARSE_CAPACITY")
     if cap is not None:
         return int(cap)
-    cap = max(128, -(-n_candidates // 8))
+    most = int(_device_bytes() * START_MEMORY_SHARE) // 12 // 128 * 128
+    cap = max(128, min(-(-n_candidates // 8), most))
     return -(-cap // 128) * 128
 
 
@@ -355,9 +373,11 @@ class ThresholdJoinEmitter(SweepEmitter):
                           self.is_self.astype(jnp.int32),
                           self.ga, self.gb, self.nv_lo, self.nv_hi],
                          axis=1)                           # [n_pairs, 6]
-        vals, ei, ej, count = batch_fn(quorum, self.lo, self.hi, meta)
+        vals, ei, ej, count, *stats = batch_fn(quorum, self.lo, self.hi,
+                                               meta)
         return SparseHits(vals=vals, i=ei, j=ej,
-                          count=count.reshape(()).astype(jnp.int32))
+                          count=count.reshape(()).astype(jnp.int32),
+                          stats=stats[0] if stats else None)
 
     def scan_init(self):
         """Empty compaction buffers + zero true count (varying-marked)."""
@@ -480,9 +500,9 @@ def quorum_allpairs_threshold(
     DESIGN.md section 4 (``REPRO_ALLPAIRS_MODE`` honored); ``prefilter``
     toggles the norm-bound tile skip (:func:`pair_score_bounds`);
     ``n_valid`` (static int) invalidates global rows >= n_valid (corpus
-    padding); ``batch_fn(quorum, lo, hi, meta) -> (vals, i, j, count)``
-    is the fused-kernel hook (kernels.ops.pairwise_threshold), batched
-    mode only.
+    padding); ``batch_fn(quorum, lo, hi, meta) -> (vals, i, j, count[,
+    stats])`` is the fused-kernel hook (kernels.ops.pairwise_threshold),
+    batched mode only.
     """
     if metric not in JOIN_METRICS:
         raise ValueError(f"metric must be one of {JOIN_METRICS}, "
@@ -574,10 +594,12 @@ class JoinResult:
 @functools.lru_cache(maxsize=64)
 def _join_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
              metric: str, mode: str, capacity: int, prefilter: bool,
-             use_kernel: bool, placement):
+             use_kernel: bool, placement, slot: int | None = None):
     """Build (and cache) the jitted distributed join program — one trace
-    per (mesh, shape, threshold, capacity, ...) key, reused across
-    escalation retries at the same capacity and repeated joins."""
+    per (mesh, shape, threshold, capacity, slot, ...) key, reused across
+    escalation retries at the same capacity and repeated joins.  With
+    ``use_kernel`` the program also returns the kernel's per-device
+    ``stats`` [P, 2] (``slot``: its slot-row width)."""
     from jax.sharding import PartitionSpec as PS
     sched = placement.schedule()
     mask_table = jnp.asarray(pair_mask_table(sched))
@@ -590,21 +612,31 @@ def _join_fn(mesh, axis_name: str, N: int, block: int, threshold: float,
                 "the fused kernel only replaces the batched inner step")
         batch_fn = functools.partial(
             kops.pairwise_threshold, threshold=threshold, capacity=capacity,
-            block_rows=block, metric=metric)
+            block_rows=block, metric=metric, slot=slot)
 
     def body(xb, mb):
         hits = quorum_allpairs_threshold(
             xb, threshold=threshold, axis_name=axis_name, capacity=capacity,
             schedule=sched, mask=mb, metric=metric, mode=mode,
             n_valid=N, prefilter=prefilter, batch_fn=batch_fn)
-        return hits.vals, hits.i, hits.j, hits.count.reshape(1)
+        out = (hits.vals, hits.i, hits.j, hits.count.reshape(1))
+        if use_kernel:
+            out += (hits.stats.reshape(1, 2),)
+        return out
 
     spec = PS(axis_name)
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec),
-        out_specs=(spec, spec, spec, spec),
+        out_specs=(spec,) * (5 if use_kernel else 4),
         check_vma=kops.shard_map_check_vma(use_kernel)))
     return lambda xs: fn(xs, mask_table)
+
+
+# per join program key and starting capacity, the (capacity, slot) that
+# sufficed last time: same-shaped joins start there, so a run of them
+# escalates once at most (DESIGN.md 11.2)
+_LAST_FIT: dict = {}
+_LAST_FIT_KEYS = 64
 
 
 def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
@@ -619,11 +651,14 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
     into P quorum blocks, runs :func:`quorum_allpairs_threshold` under
     the selected placement (None defers to ``REPRO_PLACEMENT``), and
     applies the two-pass capacity escalation — whenever any device's
-    overflow flag is set, the per-device ``capacity`` doubles and the
-    join re-runs (a fresh jit at each capacity; the kept work is only the
+    overflow flag is set, the per-device ``capacity`` doubles (and, where
+    a fused-kernel step dropped hits, its slot row widens) and the join
+    re-runs (a fresh jit at each capacity; the kept work is only the
     cheap rejected majority, which is the point of the workload).  With
     ``escalate=False`` an overflowing pass returns its valid prefix with
-    ``overflow=True`` instead of retrying.
+    ``overflow=True`` instead of retrying.  Without an explicit
+    ``capacity`` a join starts at what sufficed for the last join of the
+    same program, else at :func:`default_capacity`.
 
     ``use_kernel`` routes the batched inner step through the fused Pallas
     kernel (kernels/pairwise_threshold.py); ``prefilter`` toggles the
@@ -645,71 +680,98 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
             axis_name=axis_name, metric=metric, mode=mode,
             placement=placement, capacity=capacity, use_kernel=use_kernel,
             escalate=escalate, max_doublings=max_doublings)
-    corpus = np.asarray(corpus, np.float32)
-    N, d = corpus.shape
-    if N >= MAX_ROWS_F32_EXACT:
-        raise ValueError(
-            f"corpus has {N} rows >= 2^24; global row ids would lose "
-            "float32 exactness in the fused kernel's compaction")
-    P = mesh.shape[axis_name]
+    from ..kernels.pairwise_threshold import SLOT
     from .placement import placement_from_env, resolve_placement
+    N, d = np.shape(corpus)
+    P = mesh.shape[axis_name]
     plc = (placement_from_env(P) if placement is None
            else resolve_placement(placement, P))
     block = -(-N // P)
-    x = np.zeros((P * block, d), np.float32)
-    x[:N] = corpus
-    xs = shard_rows(x, mesh, axis_name)
     sched = plc.schedule()
     n_cand = sched.n_pairs * block * block
-    cap = int(capacity) if capacity is not None else default_capacity(n_cand)
+    if capacity is None:
+        start = default_capacity(n_cand)
+        key = (mesh, axis_name, N, block, float(threshold), metric, mode,
+               prefilter, bool(use_kernel), plc, start)
+        cap, slot = _LAST_FIT.get(key, (start, SLOT))
+    else:
+        key, cap, slot = None, int(capacity), SLOT
 
     escalations = 0
+    # host spans of one join (DESIGN.md section 14.1): the device idles
+    # in all but join.wait
     tr = obs_trace.get_tracer()
     with tr.span("sparse.join", N=N, P=P, metric=metric, mode=mode,
                  threshold=float(threshold), placement=plc.name):
+        with tr.span("join.stage", rows=N, P=P):
+            # a padded copy only where P does not divide N
+            x = np.asarray(corpus, np.float32)
+            if P * block != N:
+                x = np.zeros((P * block, d), np.float32)
+                x[:N] = corpus
+        with tr.span("join.upload", rows=N, P=P):
+            xs = jax.block_until_ready(shard_rows(x, mesh, axis_name))
         while True:
-            run = _join_fn(mesh, axis_name, N, block, float(threshold),
-                           metric, mode, cap, prefilter, use_kernel, plc)
-            vals, gi, gj, counts = (np.asarray(a) for a in run(xs))
+            with tr.span("join.dispatch", rows=N, P=P, capacity=cap):
+                run = _join_fn(mesh, axis_name, N, block, float(threshold),
+                               metric, mode, cap, prefilter, use_kernel, plc,
+                               slot if use_kernel else None)
+                out = run(xs)
+            with tr.span("join.wait", rows=N, P=P):
+                jax.block_until_ready(out)
+            with tr.span("join.fetch", rows=N, P=P):
+                vals, gi, gj, counts, *stats = (np.asarray(a) for a in out)
             counts = counts.reshape(-1)
-            overflow = bool((counts > cap).any())
+            stats = stats[0].reshape(P, 2) if stats else np.zeros((P, 2),
+                                                                  np.int32)
+            wide = bool((counts > cap).any())
+            short = bool((stats[:, 1] > 0).any())
+            overflow = wide or short
             if (not overflow or not escalate
                     or escalations >= max_doublings):
                 break
-            cap = 2 * cap
+            cap = 2 * cap if wide else cap
+            slot = 2 * slot if short else slot
             escalations += 1
-    if tr:
-        tr.count("sparse.tiles_scheduled", P * sched.n_pairs)
-        tr.count("sparse.candidates", P * n_cand)
-        if prefilter:
-            tr.count("sparse.tiles_pruned",
-                     _count_pruned_tiles(x, N, block, sched,
-                                         float(threshold), metric))
-        tr.count("sparse.escalations", escalations)
-    if overflow and escalate:
-        raise RuntimeError(
-            f"similarity join still overflows capacity {cap} after "
-            f"{escalations} doublings; raise `capacity`/`max_doublings` "
-            "or the threshold")
+        if key is not None and not overflow:
+            _LAST_FIT.pop(key, None)
+            _LAST_FIT[key] = (cap, slot)
+            while len(_LAST_FIT) > _LAST_FIT_KEYS:
+                _LAST_FIT.pop(next(iter(_LAST_FIT)))
+        if tr:
+            tr.count("sparse.tiles_scheduled", P * sched.n_pairs)
+            tr.count("sparse.candidates", P * n_cand)
+            if prefilter:
+                tr.count("sparse.tiles_pruned",
+                         _count_pruned_tiles(x, N, block, sched,
+                                             float(threshold), metric))
+            tr.count("sparse.escalations", escalations)
+            if use_kernel:
+                for dev in range(P):
+                    tr.count("pairwise_threshold.emit_steps",
+                             int(stats[dev, 0]), device=dev)
+                    tr.count("pairwise_threshold.hits", int(counts[dev]),
+                             device=dev)
+        if overflow and escalate:
+            raise RuntimeError(
+                f"similarity join still overflows capacity {cap} (slot "
+                f"{slot}) after {escalations} doublings; raise "
+                "`capacity`/`max_doublings` or the threshold")
 
-    vals = vals.reshape(P, -1)
-    gi = gi.reshape(P, -1)
-    gj = gj.reshape(P, -1)
-    keep_i, keep_j, keep_v = [], [], []
-    for dev in range(P):
-        n = min(int(counts[dev]), cap)
-        keep_i.append(gi[dev, :n])
-        keep_j.append(gj[dev, :n])
-        keep_v.append(vals[dev, :n])
-    ai = np.concatenate(keep_i)
-    aj = np.concatenate(keep_j)
-    av = np.concatenate(keep_v)
-    order = np.lexsort((aj, ai))
+        with tr.span("join.sort", rows=N, P=P):
+            vals = vals.reshape(P, -1)
+            gi = gi.reshape(P, -1)
+            gj = gj.reshape(P, -1)
+            kept = np.minimum(counts - stats[:, 1], cap)
+            ai = np.concatenate([gi[dev, :kept[dev]] for dev in range(P)])
+            aj = np.concatenate([gj[dev, :kept[dev]] for dev in range(P)])
+            av = np.concatenate([vals[dev, :kept[dev]] for dev in range(P)])
+            order = np.lexsort((aj, ai))
+            ai, aj, av = ai[order], aj[order], av[order]
     if tr:
         tr.count("sparse.pairs_emitted", int(ai.shape[0]))
-    return JoinResult(i=ai[order], j=aj[order], scores=av[order],
-                      counts=counts, capacity=cap, escalations=escalations,
-                      overflow=overflow)
+    return JoinResult(i=ai, j=aj, scores=av, counts=counts, capacity=cap,
+                      escalations=escalations, overflow=overflow)
 
 
 def _count_pruned_tiles(x: np.ndarray, N: int, block: int,

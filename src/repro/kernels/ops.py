@@ -15,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import ref
 from .flash_attention import flash_attention_pallas
@@ -43,10 +44,10 @@ def shard_map_check_vma(use_kernel: bool) -> bool:
 
 
 def _join_kernel_lowers(name: str) -> None:
-    """The fused join kernels compact hits with an in-tile cumsum and a
-    [block^2, capacity] one-hot matmul: Mosaic has no cumsum lowering and
-    the one-hot overruns VMEM at any real block, so on a compiling
-    backend they raise here with the reason (ROADMAP S4)."""
+    """The quantized join kernel compacts hits with an in-tile cumsum and
+    a [block^2, capacity] one-hot matmul: Mosaic has no cumsum lowering
+    and the one-hot overruns VMEM at any real block, so on a compiling
+    backend it raises here with the reason (ROADMAP S4)."""
     if jax.default_backend() != "cpu":
         raise NotImplementedError(
             f"{name}: the fused join kernel does not lower for "
@@ -150,36 +151,33 @@ def query_topk(stack, queries, mask, gidx, *, topk, metric="dot"):
     return vals[:Q], idx[:Q]
 
 
-@functools.partial(jax.jit, static_argnames=("threshold", "capacity",
-                                             "block_rows", "metric"))
 def pairwise_threshold(quorum, lo, hi, meta, *, threshold, capacity,
-                       block_rows, metric="dot"):
+                       block_rows, metric="dot", slot=None):
     """Fused thresholded-join step for the sparse engine's ``batch_fn``
     hook (core/sparse.py; DESIGN.md section 11).
 
-    quorum: [k, block, d]; lo/hi: [n_pairs] slot ids; meta: [n_pairs, 6]
-    int32 ``(active, is_self, ga, gb, nv_lo, nv_hi)``.  ``threshold`` is
-    a *static* float (the kernel bakes it in; the host join program is
-    cached per threshold), ``capacity`` the per-device buffer size,
-    ``block_rows`` the global block stride for row-id math.  Returns
-    ``(vals f32 [capacity], i i32 [capacity], j i32 [capacity],
-    count i32 [])`` under the overflow contract of DESIGN.md 11.2.
+    quorum: [k, block, d]; lo/hi: [n_pairs] concrete slot ids (the
+    schedule's: which pairs are self tiles is read from them on the host,
+    so this hook is not jitted itself and runs inside the caller's jit);
+    meta: [n_pairs, 6] int32 ``(active, is_self, ga, gb, nv_lo, nv_hi)``.
+    ``threshold`` is a *static* float (the kernel bakes it in; the host
+    join program is cached per threshold), ``capacity`` the per-device
+    buffer size, ``block_rows`` the global block stride for row-id math,
+    ``slot`` the hits one tile step can hand over (default
+    kernels/pairwise_threshold.py ``SLOT``).  Returns ``(vals f32
+    [capacity], i i32 [capacity], j i32 [capacity], count i32 [], stats
+    i32 [2])`` under the overflow contract of DESIGN.md 11.2; ``stats``
+    is (tile steps that emitted, hits dropped by a full slot row).
 
-    Pads block rows up to the 8-sublane multiple with zero rows — exact,
-    the valid-row bounds in ``meta`` already reject them — and capacity
-    up to the 128-lane multiple, slicing back (the dropped tail keeps the
-    first-``capacity`` prefix semantics).  Raises on TPU (see
-    :func:`_join_kernel_lowers`).
+    The kernel zero-pads block rows up to its tile multiple: exact, the
+    valid-row bounds in ``meta`` reject them.
     """
-    from .pairwise_threshold import pairwise_threshold_pallas
-    _join_kernel_lowers("pairwise_threshold")
-    q, _ = _pad_to(quorum, 8, 1)
-    capp = -(-capacity // 128) * 128
-    vals, gi, gj, count = pairwise_threshold_pallas(
-        q, lo, hi, meta, threshold=threshold, capacity=capp,
-        block_rows=block_rows, metric=metric, interpret=_interpret())
-    return (vals[:capacity], gi[:capacity], gj[:capacity],
-            count.reshape(()))
+    from .pairwise_threshold import SLOT, pairwise_threshold_pallas
+    return pairwise_threshold_pallas(
+        quorum, np.asarray(lo, np.int32), np.asarray(hi, np.int32), meta,
+        threshold=float(threshold), capacity=int(capacity),
+        block_rows=int(block_rows), metric=metric, slot=int(slot or SLOT),
+        interpret=_interpret())
 
 
 def pairwise_topk(quorum, lo, hi, meta, *, topk, block_rows, metric="dot",

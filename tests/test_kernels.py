@@ -115,17 +115,54 @@ def test_query_topk_kernel(k, block, d, Q, topk, metric):
                                rtol=1e-5, atol=1e-5)
 
 
+def _hit_rows(vals, gi, gj, n):
+    """The first n (i, j, score) entries of a join buffer as an array of
+    rows sorted by (i, j, score bits): the buffer order is the kernel's
+    step order, the oracle's is row-major (DESIGN.md 11.3)."""
+    vals, gi, gj = (np.asarray(a)[:n] for a in (vals, gi, gj))
+    rows = np.stack([gi.astype(np.int64), gj.astype(np.int64),
+                     vals.view(np.int32).astype(np.int64)], 1)
+    return rows[np.lexsort(rows.T[::-1])]
+
+
+def _assert_same_hits(got, want, n, exact):
+    """Equal (i, j) sets, and scores bit-identical where ``exact``.  Else
+    they agree to a few ulps: the CPU oracle's batched einsum may sum a
+    dot's products in another order than the kernel's tile dot, and
+    contracts its l2 epilogue differently (the thresholds sit in score
+    gaps, so membership agrees)."""
+    g, w = _hit_rows(*got[:3], n), _hit_rows(*want[:3], n)
+    np.testing.assert_array_equal(g[:, :2], w[:, :2])
+    gv, wv = (np.asarray(x[:, 2], np.int32).view(np.float32) for x in (g, w))
+    if exact:
+        np.testing.assert_array_equal(gv, wv)
+    else:
+        np.testing.assert_allclose(gv, wv, rtol=1e-6, atol=1e-5)
+
+
+def _gap_threshold(quorum, metric, share=0.3):
+    """A threshold in a score gap near the top ``share`` of the first and
+    last slots' scores under the metric."""
+    from repro.core.sparse import threshold_with_gap
+    s = np.asarray(quorum[0] @ quorum[-1].T)
+    if metric == "l2":
+        n0 = np.asarray((quorum[0] ** 2).sum(-1))
+        n1 = np.asarray((quorum[-1] ** 2).sum(-1))
+        s = 2.0 * s - n1[None, :] - n0[:, None]
+    return threshold_with_gap(s, share, min_gap=1e-4)
+
+
 @pytest.mark.parametrize("k,block,d,n_pairs,capacity",
                          [(3, 16, 8, 4, 256), (4, 12, 24, 6, 64),
                           (2, 8, 4, 2, 128), (5, 8, 16, 8, 16)])
 @pytest.mark.parametrize("metric", ["dot", "l2"])
 def test_pairwise_threshold_kernel(k, block, d, n_pairs, capacity, metric):
-    """Fused thresholded scoring + sparse compaction kernel vs the jnp
-    cumsum oracle: identical compacted (score, i, j) buffers and true
-    counts, including inactive (prefiltered) tiles, a self pair with the
-    strict-triangle rule, partial row validity, capacity overflow (the
-    (5, 8, 16, 8, 16) cell), and non-multiple-of-8 handling through the
-    ops wrapper."""
+    """Fused thresholded scoring + hit emission kernel vs the jnp cumsum
+    oracle: the same (score, i, j) hits and true counts, including
+    inactive (prefiltered) tiles, a self pair with the strict-triangle
+    rule, partial row validity, capacity overflow (the (5, 8, 16, 8, 16)
+    cell: the kept hits are a valid prefix) and non-multiple-of-8
+    blocks."""
     rng = np.random.default_rng(k * 1000 + block)   # order-independent
     quorum = jnp.asarray(rng.normal(size=(k, block, d)), jnp.float32)
     lo = rng.integers(0, k, size=n_pairs).astype(np.int32)
@@ -141,33 +178,117 @@ def test_pairwise_threshold_kernel(k, block, d, n_pairs, capacity, metric):
     ], axis=1).astype(np.int32)
     if n_pairs > 1:
         meta[1, 0] = 0                              # a prefiltered tile
-    # a mid-quantile threshold (under the metric) so both branches of the
-    # compare are hit
-    s = np.asarray(quorum[0] @ quorum[-1].T)
-    if metric == "l2":
-        n0 = np.asarray((quorum[0] ** 2).sum(-1))
-        n1 = np.asarray((quorum[-1] ** 2).sum(-1))
-        s = 2.0 * s - n1[None, :] - n0[:, None]
-    thr = float(np.quantile(s, 0.7))
+    thr = _gap_threshold(quorum, metric)
     got = ops.pairwise_threshold(quorum, lo, hi, jnp.asarray(meta),
                                  threshold=thr, capacity=capacity,
                                  block_rows=block, metric=metric)
-    pad = (-block) % 8                              # ref sees padded rows
-    qp = jnp.pad(quorum, ((0, 0), (0, pad), (0, 0)))
-    capp = -(-capacity // 128) * 128
-    want = ref.pairwise_threshold(qp, lo, hi, meta, threshold=thr,
-                                  capacity=capp, block_rows=block,
+    want = ref.pairwise_threshold(quorum, lo, hi, meta, threshold=thr,
+                                  capacity=4096, block_rows=block,
                                   metric=metric)
-    np.testing.assert_array_equal(np.asarray(got[1]),
-                                  np.asarray(want[1])[:capacity])
-    np.testing.assert_array_equal(np.asarray(got[2]),
-                                  np.asarray(want[2])[:capacity])
-    np.testing.assert_allclose(np.asarray(got[0]),
-                               np.asarray(want[0])[:capacity],
-                               rtol=1e-5, atol=1e-5)
-    assert int(got[3]) == int(want[3])
-    if (k, block, d, n_pairs, capacity) == (5, 8, 16, 8, 16):
-        assert int(got[3]) > capacity               # overflow cell flags
+    count = int(want[3])
+    assert int(got[3]) == count
+    assert int(got[4][1]) == 0                      # no slot row overflowed
+    if count <= capacity:
+        _assert_same_hits(got, want, count, exact=metric == "dot")
+    else:                                           # the overflow cell
+        assert (k, block, d, n_pairs, capacity) == (5, 8, 16, 8, 16)
+        kept = _hit_rows(*got[:3], capacity)
+        full = {tuple(r[:2]) for r in _hit_rows(*want[:3], count)}
+        assert {tuple(r[:2]) for r in kept} <= full
+        assert (np.asarray(got[1])[:capacity] != ops.ref.IDX_SENTINEL).all()
+
+
+# (k, block, d, lo, hi, plan): several row and column steps a block, a
+# self tile's diagonal steps, padded rows (block -> a multiple of tc), an
+# odd column-tile count (a fold with one column tile), cross tiles; plan
+# is the (rows, cols, wide) of a small ``tile_plan``
+THRESHOLD_TILE_CASES = [
+    (1, 40, 16, [0], [0], (8, 16, 16)),
+    (3, 36, 8, [0, 0, 1, 2], [0, 1, 2, 2], (8, 8, 8)),
+    (2, 30, 16, [0, 1, 0], [1, 1, 0], (8, 16, 16)),
+]
+
+
+def _small_tiles(monkeypatch, rows, cols, wide):
+    """The kernel's tiling of a large block at a small size: the
+    production ``tile_plan`` and padding, with small tiles."""
+    from repro.kernels import pairwise_threshold as pt
+    monkeypatch.setattr(pt, "tile_plan", functools.partial(
+        pt.tile_plan, rows=rows, cols=cols, wide=wide))
+    return pt.tile_plan
+
+
+def _tile_case(k, block, d, lo, hi, active=None, seed=0):
+    # small integers: every product and partial sum is exact in float32,
+    # so a score is the same whatever order a path sums it in, and the
+    # kernel's scores must equal the oracle's bit for bit
+    rng = np.random.default_rng(seed + 17 * block)
+    quorum = jnp.asarray(rng.integers(-4, 5, size=(k, block, d)),
+                         jnp.float32)
+    lo, hi = np.asarray(lo, np.int32), np.asarray(hi, np.int32)
+    nv = np.full(k, block)
+    nv[-1] = block - 5                              # a ragged last block
+    meta = np.stack([np.ones(len(lo)) if active is None else active,
+                     lo == hi, lo, hi, nv[lo], nv[hi]],
+                    axis=1).astype(np.int32)
+    return quorum, lo, hi, meta
+
+
+@pytest.mark.parametrize("case", THRESHOLD_TILE_CASES,
+                         ids=["self", "self+cross", "cross+self"])
+@pytest.mark.parametrize("metric", ["dot", "l2"])
+def test_pairwise_threshold_kernel_tiles(case, metric, monkeypatch):
+    """The tiled kernel over blocks of several [tr, tc] steps against the
+    oracle: the (i, j) set equal, scores bit-identical, the count true,
+    and an inactive (masked) tile emitting nothing."""
+    k, block, d, lo, hi, plan = case
+    tr, tc, bp = _small_tiles(monkeypatch, *plan)(block)
+    assert (tr, tc) == plan[:2] and bp > block and bp // tr > 2
+    active = np.ones(len(lo))
+    active[-1] = 0 if len(lo) > 2 else 1
+    quorum, lo, hi, meta = _tile_case(k, block, d, lo, hi, active)
+    thr = _gap_threshold(quorum, metric, share=0.2)   # between integers
+    got = ops.pairwise_threshold(quorum, lo, hi, jnp.asarray(meta),
+                                 threshold=thr, capacity=4096,
+                                 block_rows=block, metric=metric)
+    want = ref.pairwise_threshold(quorum, lo, hi, meta, threshold=thr,
+                                  capacity=4096, block_rows=block,
+                                  metric=metric)
+    count = int(want[3])
+    assert count > 0 and int(got[3]) == count
+    assert int(got[4][1]) == 0 and int(got[4][0]) > 1   # several steps emit
+    _assert_same_hits(got, want, count, exact=True)
+
+
+@pytest.mark.parametrize("limit", ["capacity", "slot"])
+def test_pairwise_threshold_kernel_overflow_contract(limit, monkeypatch):
+    """Past the global capacity, or past a step's slot row, the count
+    stays true and the kept hits are a valid prefix; the larger capacity
+    or the wider slot gives back the full answer."""
+    assert _small_tiles(monkeypatch, 8, 16, 16)(32) == (8, 16, 32)
+    quorum, lo, hi, meta = _tile_case(2, 32, 8, [0, 0], [0, 1])
+    thr = _gap_threshold(quorum, "dot", share=0.5)
+    want = ref.pairwise_threshold(quorum, lo, hi, meta, threshold=thr,
+                                  capacity=4096, block_rows=32)
+    count = int(want[3])
+    full = {tuple(r[:2]) for r in _hit_rows(*want[:3], count)}
+    small = dict(capacity=count // 2, slot=None) if limit == "capacity" \
+        else dict(capacity=4096, slot=4)
+    got = ops.pairwise_threshold(quorum, lo, hi, jnp.asarray(meta),
+                                 threshold=thr, block_rows=32, **small)
+    assert int(got[3]) == count                     # the true count
+    dropped = int(got[4][1])
+    kept = min(count - dropped, small["capacity"])
+    assert (dropped > 0) == (limit == "slot")
+    assert kept < count
+    rows = _hit_rows(*got[:3], kept)
+    assert {tuple(r[:2]) for r in rows} <= full and len(rows) == kept
+    assert (np.asarray(got[1])[kept:] == ops.ref.IDX_SENTINEL).all()
+    wide = dict(capacity=4096, slot=8 * 16)
+    again = ops.pairwise_threshold(quorum, lo, hi, jnp.asarray(meta),
+                                   threshold=thr, block_rows=32, **wide)
+    assert int(again[4][1]) == 0
+    _assert_same_hits(again, want, count, exact=True)
 
 
 @pytest.mark.parametrize("k,block,n_pairs", [(2, 8, 2), (3, 12, 5),

@@ -146,12 +146,24 @@ def test_lowering_error_propagates(monkeypatch, target, attr, call):
 
 
 def test_join_kernels_refuse_to_compile_for_tpu(monkeypatch):
-    """The fused join kernels have no TPU lowering (in-tile cumsum
-    compaction); on a compiling backend their hooks raise with that
-    reason before any launch."""
+    """The quantized join kernel has no TPU lowering (in-tile cumsum
+    compaction): on a compiling backend its hook raises with that reason
+    before any launch.  The f32 join kernel lowers: its hook no longer
+    raises there and launches the kernel compiled (recorded by a shim
+    that stands in for the launch, which this CPU cannot run compiled)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="ROADMAP S4"):
-        _call_threshold()
+    seen = {}
+
+    def launch(quorum, lo, hi, meta, *, capacity, interpret, **kwargs):
+        seen["interpret"] = interpret
+        z = jnp.zeros((capacity,), jnp.int32)
+        return (z.astype(jnp.float32), z, z, jnp.int32(0),
+                jnp.zeros((2,), jnp.int32))
+
+    monkeypatch.setattr(pt_mod, "pairwise_threshold_pallas", launch)
+    ops.pairwise_threshold(*_pair_args(26), threshold=0.4, capacity=100,
+                           block_rows=26)
+    assert seen == {"interpret": False}
     with pytest.raises(NotImplementedError, match="ROADMAP S4"):
         _call_threshold_q()
 

@@ -24,6 +24,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from repro.core import sparse
 from repro.core.placement import registered_placements
 from repro.core.sparse import (brute_force_join, default_capacity,
                                threshold_for_selectivity)
@@ -134,8 +135,74 @@ def test_default_capacity_env(monkeypatch):
     assert default_capacity(1) == 128                  # floor
     assert default_capacity(1 << 20) == (1 << 20) // 8  # 1/8, already x128
     assert default_capacity(1000) == 128               # ceil(125) -> 128
+    # a start 1/8 of the candidates cannot allocate: it is bounded by a
+    # small share of the device's memory (a TPU v5e's on the CPU)
+    most = default_capacity(1 << 36)
+    assert most % 128 == 0
+    assert 12 * most <= (16 << 30) * sparse.START_MEMORY_SHARE
     monkeypatch.setenv("REPRO_SPARSE_CAPACITY", "512")
     assert default_capacity(1 << 30) == 512            # override wins
     monkeypatch.setenv("REPRO_SPARSE_CAPACITY", "0")
     with pytest.raises(ValueError, match="REPRO_SPARSE_CAPACITY"):
         default_capacity(1)
+
+
+JOIN_KERNEL_CASES = [(1, "cyclic"), (4, "cyclic"), (7, "cyclic"),
+                     (7, "projective")]
+
+
+@pytest.mark.parametrize("P,name", JOIN_KERNEL_CASES,
+                         ids=[f"{n}-P{P}" for P, n in JOIN_KERNEL_CASES])
+def test_similarity_join_kernel_matches_brute_force(P, name):
+    """``similarity_join`` through the fused kernel equals the dense
+    oracle for both metrics, with a ragged corpus tail, at one block (a
+    self tile only) and under cyclic and plane quorums."""
+    code = f"""
+import numpy as np
+from repro.core.sparse import (brute_force_join, similarity_join,
+                               threshold_for_selectivity)
+from repro.launch.mesh import make_mesh
+import jax
+mesh = make_mesh(({P},), ("q",), devices=jax.devices()[:{P}])
+rng = np.random.default_rng({P})
+corpus = rng.normal(size=({P} * 24 - 5, 16)).astype(np.float32)
+for metric in ("dot", "l2"):
+    thr = threshold_for_selectivity(corpus, 0.05, metric)
+    wi, wj, wv = brute_force_join(corpus, thr, metric)
+    res = similarity_join(corpus, mesh, threshold=thr, metric=metric,
+                          mode="batched", placement={name!r},
+                          use_kernel=True, quant="off")
+    assert not res.overflow and len(wi) > 0
+    np.testing.assert_array_equal(res.i, wi)
+    np.testing.assert_array_equal(res.j, wj)
+    np.testing.assert_allclose(res.scores, wv, rtol=1e-6, atol=1e-5)
+print("JOIN-KERNEL-OK")
+"""
+    out = run_sub(code, P)
+    assert "JOIN-KERNEL-OK" in out
+
+
+def test_capacity_remembered_across_joins(monkeypatch):
+    """A join that had to escalate leaves its capacity and slot to the
+    next join of the same program, which escalates no more; both equal
+    the oracle."""
+    import jax
+    from repro.core.sparse import similarity_join
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setenv("REPRO_SPARSE_CAPACITY", "128")
+    mesh = make_mesh((1,), ("q",), devices=jax.devices()[:1])
+    rng = np.random.default_rng(11)
+    first, second = (rng.normal(size=(48, 8)).astype(np.float32)
+                     for _ in range(2))
+    thr = threshold_for_selectivity(first, 0.25)
+    runs = []
+    for corpus in (first, second):
+        res = similarity_join(corpus, mesh, threshold=thr, mode="batched",
+                              use_kernel=True, quant="off")
+        wi, wj, _ = brute_force_join(corpus, thr)
+        np.testing.assert_array_equal(res.i, wi)
+        np.testing.assert_array_equal(res.j, wj)
+        runs.append(res)
+    assert runs[0].escalations >= 1 and runs[0].n_pairs > 128
+    assert runs[1].escalations == 0
+    assert runs[1].capacity == runs[0].capacity

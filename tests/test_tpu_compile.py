@@ -178,3 +178,52 @@ def test_knn_program_names_its_kernel_and_phases(topo, monkeypatch, P):
                                        if P > 1 else set())
     for scope in scopes:
         assert f"/{scope}/" in text, scope
+
+
+JOIN_ROWS = 262_144         # the join cell: one 262,144 x 512 block at P = 1
+JOIN_D = 512
+
+
+def test_pairwise_threshold_compiles_at_the_cell_shape(one_chip):
+    """The tiled join kernel and its compaction for one 262,144 x 512 block
+    (a self tile): its VMEM use follows the tile, not the block."""
+    from repro.kernels.pairwise_threshold import pairwise_threshold_pallas
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    lo = hi = np.zeros(1, np.int32)
+    fn = lambda x, meta: pairwise_threshold_pallas(
+        x, lo, hi, meta, threshold=0.9, capacity=1 << 20,
+        block_rows=JOIN_ROWS, metric="dot")
+    _compile_for_tpu(fn, spec((1, JOIN_ROWS, JOIN_D), jnp.float32),
+                     spec((1, 6), jnp.int32))
+
+
+@pytest.mark.parametrize("P", [1, 4])
+def test_join_program_compiles_inside_shard_map(topo, monkeypatch, P):
+    """The whole ``similarity_join`` program with the kernel, at P = 1 and
+    at P = 4 cyclic on ``v5e:2x2``: the kernel compiles inside shard_map
+    under varying-axis typing and its custom call reads
+    ``%pairwise_threshold.N``."""
+    import re
+    from repro.core import sparse
+    from repro.core.placement import resolve_placement
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = make_mesh((P,), ("q",), devices=topo.devices[:P])
+    block = 8192
+    run = sparse._join_fn(mesh, "q", P * block, block, 0.9, "dot",
+                          "batched", 1 << 16, True, True,
+                          resolve_placement("cyclic", P), 128)
+    cells = [c.cell_contents for c in run.__closure__]
+    fn = next(c for c in cells if hasattr(c, "lower"))
+    mask_table = next(c for c in cells if hasattr(c, "shape"))
+    rows = NamedSharding(mesh, PS("q"))
+    text = _compile_for_tpu(
+        fn, jax.ShapeDtypeStruct((P * block, JOIN_D), jnp.float32,
+                                 sharding=rows),
+        jax.ShapeDtypeStruct(mask_table.shape, mask_table.dtype,
+                             sharding=rows)).as_text()
+    calls = re.findall(r"^\s*%(\S+) = .*custom-call\(.*tpu_custom_call", text,
+                       re.M)
+    assert calls and all(c.startswith("pairwise_threshold.")
+                         for c in calls), calls
