@@ -52,7 +52,7 @@ from jax import lax
 
 from ..kernels.ref import IDX_SENTINEL, NEG_INF, PRECISION
 from ..kernels.ref import QUERY_METRICS as KNN_METRICS
-from ..launch.mesh import make_mesh, shard_rows
+from ..launch.mesh import make_mesh, stage_rows
 from ..obs import trace as obs_trace
 from . import sweep as sweep_mod
 from .scheduler import PairSchedule
@@ -386,8 +386,9 @@ def knn_graph(corpus, mesh, *, topk: int, axis_name: str = "q",
     """The k-NN graph of ``corpus`` rows, exactly (DESIGN.md section
     12.3).
 
-    The host entry point: pads the [N, d] corpus into P quorum blocks,
-    runs :func:`quorum_allpairs_knn` under the selected placement (None
+    The host entry point: stages the [N, d] corpus as P quorum blocks
+    (:func:`launch.mesh.stage_rows`, zero rows past N), runs
+    :func:`quorum_allpairs_knn` under the selected placement (None
     defers to ``REPRO_PLACEMENT``), and slices the padding rows back
     off.  ``use_kernel`` routes the batched inner step through the fused
     Pallas kernel (kernels/pairwise_topk.py).  ``quant`` selects the
@@ -415,14 +416,14 @@ def knn_graph(corpus, mesh, *, topk: int, axis_name: str = "q",
     # host spans of one build (DESIGN.md section 14.1): the device idles
     # in all but knn.wait
     tr = obs_trace.get_tracer()
-    with tr.span("knn.stage", rows=N, P=P):
-        corpus = np.asarray(corpus, np.float32)
-        x = np.zeros((P * block, corpus.shape[1]), np.float32)
-        x[:N] = corpus
+    with tr.span("knn.stage", rows=N, P=P, padded=int(P * block != N)):
+        corpus = np.ascontiguousarray(corpus, np.float32)
     with tr.span("knn.upload", rows=N, P=P):
         # blocks until the rows are on the chips, so that the span holds
-        # the transfer; the program could not start before it anyway
-        xs = jax.block_until_ready(shard_rows(x, mesh, axis_name))
+        # the transfer and any padding; the program could not start
+        # before it anyway
+        xs = jax.block_until_ready(stage_rows(corpus, mesh, axis_name,
+                                              block))
     # with the Tracer on, the kernel also counts its merge rounds; the
     # program the Tracer does not see neither computes nor fetches them
     count = bool(tr) and use_kernel

@@ -57,7 +57,7 @@ import numpy as np
 from jax import lax
 
 from ..kernels.ref import IDX_SENTINEL, NEG_INF, PRECISION
-from ..launch.mesh import make_mesh, shard_rows
+from ..launch.mesh import make_mesh, stage_rows
 from ..obs import trace as obs_trace
 from . import env as env_mod
 from . import sweep as sweep_mod
@@ -647,14 +647,15 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
                     quant: str | None = None) -> JoinResult:
     """All pairs of ``corpus`` rows with score >= threshold, exactly once.
 
-    The host entry point (DESIGN.md section 11): pads the [N, d] corpus
-    into P quorum blocks, runs :func:`quorum_allpairs_threshold` under
-    the selected placement (None defers to ``REPRO_PLACEMENT``), and
-    applies the two-pass capacity escalation — whenever any device's
-    overflow flag is set, the per-device ``capacity`` doubles (and, where
-    a fused-kernel step dropped hits, its slot row widens) and the join
-    re-runs (a fresh jit at each capacity; the kept work is only the
-    cheap rejected majority, which is the point of the workload).  With
+    The host entry point (DESIGN.md section 11): stages the [N, d]
+    corpus as P quorum blocks (:func:`launch.mesh.stage_rows`), runs
+    :func:`quorum_allpairs_threshold` under the selected placement (None
+    defers to ``REPRO_PLACEMENT``), and applies the two-pass capacity
+    escalation — whenever any device's overflow flag is set, the
+    per-device ``capacity`` doubles (and, where a fused-kernel step
+    dropped hits, its slot row widens) and the join re-runs (a fresh jit
+    at each capacity; the kept work is only the cheap rejected majority,
+    which is the point of the workload).  With
     ``escalate=False`` an overflowing pass returns its valid prefix with
     ``overflow=True`` instead of retrying.  Without an explicit
     ``capacity`` a join starts at what sufficed for the last join of the
@@ -682,7 +683,7 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
             escalate=escalate, max_doublings=max_doublings)
     from ..kernels.pairwise_threshold import SLOT
     from .placement import placement_from_env, resolve_placement
-    N, d = np.shape(corpus)
+    N = len(corpus)
     P = mesh.shape[axis_name]
     plc = (placement_from_env(P) if placement is None
            else resolve_placement(placement, P))
@@ -703,14 +704,10 @@ def similarity_join(corpus, mesh, *, threshold: float, axis_name: str = "q",
     tr = obs_trace.get_tracer()
     with tr.span("sparse.join", N=N, P=P, metric=metric, mode=mode,
                  threshold=float(threshold), placement=plc.name):
-        with tr.span("join.stage", rows=N, P=P):
-            # a padded copy only where P does not divide N
-            x = np.asarray(corpus, np.float32)
-            if P * block != N:
-                x = np.zeros((P * block, d), np.float32)
-                x[:N] = corpus
+        with tr.span("join.stage", rows=N, P=P, padded=int(P * block != N)):
+            x = np.ascontiguousarray(corpus, np.float32)
         with tr.span("join.upload", rows=N, P=P):
-            xs = jax.block_until_ready(shard_rows(x, mesh, axis_name))
+            xs = jax.block_until_ready(stage_rows(x, mesh, axis_name, block))
         while True:
             with tr.span("join.dispatch", rows=N, P=P, capacity=cap):
                 run = _join_fn(mesh, axis_name, N, block, float(threshold),
@@ -780,8 +777,9 @@ def _count_pruned_tiles(x: np.ndarray, N: int, block: int,
     # host-side replay of the DESIGN.md 11.1 interval bound over every
     # device's scheduled tiles — the sparse.tiles_pruned counter
     P = sched.P
-    xb = x.reshape(P, block, -1)
-    norms = np.sqrt(np.sum(xb * xb, axis=-1))               # [P, block]
+    norms = np.zeros(P * block, np.float32)
+    norms[:N] = np.sqrt(np.sum(x * x, axis=-1))
+    norms = norms.reshape(P, block)
     valid = (np.arange(P * block).reshape(P, block) < N)
     maxn = np.where(valid, norms, 0.0).max(axis=-1)
     minn = np.where(valid, norms, np.inf).min(axis=-1)
@@ -931,9 +929,7 @@ def selfcheck_main(nblocks: int | None = None,
     # ppermute ring gather: every device ends with the identical stack
     from jax.sharding import PartitionSpec as PS
     sched = plc.schedule()
-    blockc = -(-N // Pn)
-    xs = np.zeros((Pn * blockc, d), np.float32)
-    xs[:N] = corpus
+    xs = stage_rows(corpus, mesh, "q", -(-N // Pn))
     mask_table = jnp.asarray(pair_mask_table(sched))
     cap = esc.capacity
 
@@ -948,7 +944,7 @@ def selfcheck_main(nblocks: int | None = None,
     spec = PS("q")
     out = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec),
-        out_specs=(spec,) * 6))(jnp.asarray(xs), mask_table)
+        out_specs=(spec,) * 6))(xs, mask_table)
     lv, li, lc, gv, gi_, gc = (np.asarray(a) for a in out)
     lv, li = lv.reshape(Pn, cap), li.reshape(Pn, cap)
     for dev in range(Pn):           # every device's gathered copy agrees

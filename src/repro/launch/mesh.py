@@ -12,9 +12,12 @@ jax import — see dryrun.py).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as PS
 
@@ -37,8 +40,43 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
 def shard_rows(x, mesh: Mesh, axis_name: str) -> jax.Array:
     """Stage a host array onto ``mesh`` with its leading axis split over
     ``axis_name``: each device receives only its own rows, instead of the
-    whole array landing on one device before shard_map reshards it."""
+    whole array landing on one device before shard_map reshards it.
+    ``x`` must already have a length the axis divides; a host corpus of
+    any length enters through :func:`stage_rows`."""
     return jax.device_put(x, NamedSharding(mesh, PS(axis_name)))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_rows(x, block: int):
+    return jnp.pad(x, ((0, block - x.shape[0]), (0, 0)))
+
+
+def stage_rows(corpus, mesh: Mesh, axis_name: str, block: int) -> jax.Array:
+    """Stage a host corpus of N rows on ``mesh`` as a [P * block, d]
+    float32 array split over ``axis_name``, rows past N zero: what
+    :func:`shard_rows` gives for the corpus padded to P * block rows,
+    without that padded host copy.  The entry for host corpora.
+
+    Where P * block == N the caller's rows go to :func:`shard_rows` as
+    they are.  Otherwise each device receives a row-slice view of them,
+    and only a short block is padded, on its own device; a device whose
+    rows all lie past N gets a block of zeros made there.  A float64 or
+    strided corpus pays the one conversion ``np.ascontiguousarray``
+    makes, and nothing more.  The caller's array is never donated, so
+    it is unchanged."""
+    x = np.ascontiguousarray(corpus, np.float32)
+    N, d = x.shape
+    P = mesh.shape[axis_name]
+    if P * block == N:
+        return shard_rows(x, mesh, axis_name)
+    shape = (P * block, d)
+    sharding = NamedSharding(mesh, PS(axis_name))
+    shards = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        rows = x[idx[0]]                 # fewer than block past N, or none
+        part = jax.device_put(rows, dev)
+        shards.append(part if len(rows) == block else _pad_rows(part, block))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

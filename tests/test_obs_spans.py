@@ -128,6 +128,13 @@ def test_knn_spans_in_the_profile_in_order(profiled):
             assert a[2] + a[3] <= b[2], (a, b)
 
 
+def test_knn_stage_says_in_the_profile_that_it_did_not_pad(profiled):
+    """P divides the 60 rows at P = 1 and 4: each ``knn.stage`` reaches
+    the profile with ``padded`` 0, as a deployment's builds must."""
+    stages = _by_name(profiled[0]["spans"], "knn.stage")
+    assert [(s[4]["P"], s[4]["padded"]) for s in stages] == [(1, 0), (4, 0)]
+
+
 def test_batch_spans_in_the_profile_nested(profiled):
     spans = profiled[0]["spans"]
     steps = _by_name(spans, "batch.step")
